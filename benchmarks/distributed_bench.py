@@ -22,8 +22,11 @@ the compiler arbitrates with).
 
 The measurement runs in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so it works
-no matter how the parent process initialized jax. Appends a trajectory
-entry to ``benchmarks/BENCH_distributed.json``.
+no matter how the parent process initialized jax. The child is a
+rehearsal on a forced CPU mesh by design, so it runs with
+``JAX_PLATFORMS=cpu``: on a TPU host the parent already holds the
+chip, and a child that asked for it would wait or fail. Appends a
+trajectory entry to ``benchmarks/BENCH_distributed.json``.
 """
 from __future__ import annotations
 
@@ -122,6 +125,7 @@ def main(rows: int = 32768, cols: int = 128, k: int = 16,
     env = dict(os.environ)
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={DEVICES}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.distributed_bench",

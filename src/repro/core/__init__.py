@@ -1,11 +1,26 @@
 """repro.core — the paper's contribution: declarative LA DSL with lineage
 tracing, lineage-based reuse, an optimizing compiler, heterogeneous
 tensors, and federated tensors (SystemDS, CIDR 2020)."""
+import os as _os
+from pathlib import Path as _Path
+
 import jax as _jax
 
 # SystemDS's numeric lifecycle semantics are double-precision; the LM
 # model zoo uses explicit f32/bf16 dtypes and is unaffected.
 _jax.config.update("jax_enable_x64", True)
+# The TPU compiler's 64-bit rewrite refuses the frontend attributes the
+# Shardy partitioner leaves on multi-device programs: a float64 solve on
+# a mesh does not compile. GSPMD partitions the same programs.
+_jax.config.update("jax_use_shardy_partitioner", False)
+
+# Persistent compile cache. JAX itself reads JAX_COMPILATION_CACHE_DIR;
+# without it the cache lives at one fixed path inside the checkout, so
+# every process of this checkout finds what an earlier one compiled.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        str(_Path(__file__).resolve().parents[3] / ".jax_cache"))
 
 from . import ops  # noqa: F401,E402
 from .batching import (BatchedPlan, BatchingError,  # noqa: F401

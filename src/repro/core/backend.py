@@ -35,15 +35,9 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import sparse as jsparse
 
 from .dag import SPARSE_THRESHOLD, Node  # single source of truth
-
-try:  # BCOO sparse support (available on CPU)
-    from jax.experimental import sparse as jsparse
-    HAS_SPARSE = True
-except Exception:  # pragma: no cover
-    jsparse = None
-    HAS_SPARSE = False
 
 # physical format names used across compiler/segments/runtime
 DENSE = "dense"
@@ -55,7 +49,7 @@ SPARSE_MIN_NUMEL = 1 << 12
 
 
 def is_sparse(x) -> bool:
-    return HAS_SPARSE and isinstance(x, jsparse.BCOO)
+    return isinstance(x, jsparse.BCOO)
 
 
 def densify(x):
@@ -120,7 +114,7 @@ def maybe_sparsify(arr, sparsity_est: float):
     format assignment (`compiler.assign_formats`); this remains for
     standalone/array-level callers.
     """
-    if (HAS_SPARSE and sparsity_est < SPARSE_THRESHOLD
+    if (sparsity_est < SPARSE_THRESHOLD
             and getattr(arr, "ndim", 0) == 2
             and arr.size >= SPARSE_MIN_NUMEL):
         return jsparse.BCOO.fromdense(arr)
@@ -156,7 +150,7 @@ def leaf_format(node: Node) -> str:
         return DENSE
     if node.attr("batch") is not None:
         return DENSE
-    if (HAS_SPARSE and len(node.shape) == 2
+    if (len(node.shape) == 2
             and node.sparsity < SPARSE_THRESHOLD
             and node.numel >= SPARSE_MIN_NUMEL):
         return BCOO
@@ -193,7 +187,7 @@ def infer_format(node: Node, in_fmts: tuple[str, ...]) -> str:
     `BCOO.fromdense` inside a trace needs a static nse bound, and a
     wrong estimate would silently drop values.
     """
-    if not HAS_SPARSE or BCOO not in in_fmts:
+    if BCOO not in in_fmts:
         return DENSE
     i = bcoo_passthrough_arg(node)
     if i is not None and in_fmts[i] == BCOO:
@@ -455,47 +449,51 @@ def _build_solve(attrs):
 # -- sparse (bcoo) kernel variants -------------------------------------------
 # All jit-traceable: BCOO matmul/transpose and `todense` are primitives.
 
-if HAS_SPARSE:
-    @register_sparse_kernel("gram", (BCOO,))
-    def _sparse_gram(attrs):
-        from repro.kernels.spmm import ops as spmm_ops
-        return spmm_ops.gram_bcoo
+@register_sparse_kernel("gram", (BCOO,))
+def _sparse_gram(attrs):
+    from repro.kernels.spmm import ops as spmm_ops
+    return spmm_ops.gram_bcoo
 
-    @register_sparse_kernel("xtv", (BCOO, DENSE))
-    def _sparse_xtv(attrs):
-        from repro.kernels.spmm import ops as spmm_ops
-        return spmm_ops.xtv_bcoo
 
-    @register_sparse_kernel("matmul", (BCOO, DENSE))
-    def _sparse_matmul(attrs):
-        from repro.kernels.spmm import ops as spmm_ops
-        return spmm_ops.matmul_bcoo
+@register_sparse_kernel("xtv", (BCOO, DENSE))
+def _sparse_xtv(attrs):
+    from repro.kernels.spmm import ops as spmm_ops
+    return spmm_ops.xtv_bcoo
 
-    # (DENSE, BCOO) needs no entry: the dense fallback's densify
-    # boundary computes the identical dense @ todense(b)
-    register_sparse_kernel("matmul", (BCOO, BCOO))(
-        lambda attrs: (lambda a, b: a @ b.todense()))
-    register_sparse_kernel("t", (BCOO,), BCOO)(
-        lambda attrs: (lambda x: x.T))
 
-    def _bcoo_map(fn):
-        """Apply a zero-preserving elementwise fn to BCOO values only."""
-        def run(x):
-            return jsparse.BCOO((fn(x.data), x.indices), shape=x.shape,
-                                indices_sorted=x.indices_sorted,
-                                unique_indices=x.unique_indices)
-        return run
+@register_sparse_kernel("matmul", (BCOO, DENSE))
+def _sparse_matmul(attrs):
+    from repro.kernels.spmm import ops as spmm_ops
+    return spmm_ops.matmul_bcoo
 
-    for _op, _fn in _ZERO_PRESERVING_FNS.items():
-        register_sparse_kernel(_op, (BCOO,), BCOO)(
-            (lambda fn: lambda attrs: _bcoo_map(fn))(_fn))
 
-    # only selected when the compiler assigned a BCOO output, i.e. the
-    # dense operand is a scalar (see infer_format)
-    register_sparse_kernel("mul", (BCOO, DENSE), BCOO)(
-        lambda attrs: (lambda x, s: _bcoo_map(lambda d: d * s)(x)))
-    register_sparse_kernel("mul", (DENSE, BCOO), BCOO)(
-        lambda attrs: (lambda s, x: _bcoo_map(lambda d: s * d)(x)))
+# (DENSE, BCOO) needs no entry: the dense fallback's densify
+# boundary computes the identical dense @ todense(b)
+register_sparse_kernel("matmul", (BCOO, BCOO))(
+    lambda attrs: (lambda a, b: a @ b.todense()))
+register_sparse_kernel("t", (BCOO,), BCOO)(
+    lambda attrs: (lambda x: x.T))
+
+
+def _bcoo_map(fn):
+    """Apply a zero-preserving elementwise fn to BCOO values only."""
+    def run(x):
+        return jsparse.BCOO((fn(x.data), x.indices), shape=x.shape,
+                            indices_sorted=x.indices_sorted,
+                            unique_indices=x.unique_indices)
+    return run
+
+
+for _op, _fn in _ZERO_PRESERVING_FNS.items():
+    register_sparse_kernel(_op, (BCOO,), BCOO)(
+        (lambda fn: lambda attrs: _bcoo_map(fn))(_fn))
+
+# only selected when the compiler assigned a BCOO output, i.e. the
+# dense operand is a scalar (see infer_format)
+register_sparse_kernel("mul", (BCOO, DENSE), BCOO)(
+    lambda attrs: (lambda x, s: _bcoo_map(lambda d: d * s)(x)))
+register_sparse_kernel("mul", (DENSE, BCOO), BCOO)(
+    lambda attrs: (lambda s, x: _bcoo_map(lambda d: s * d)(x)))
 
 
 # -- sharded (shard_map) kernel variants -------------------------------------

@@ -216,7 +216,7 @@ def assign_formats(plan: "Plan", sparse: bool) -> dict[int, str]:
     """
     from . import backend
     fmt: dict[int, str] = {}
-    if not sparse or not backend.HAS_SPARSE:
+    if not sparse:
         return fmt  # empty mapping ≡ all dense
     seen_leaves: set[int] = set()
     for ins in plan.instructions:
@@ -501,7 +501,7 @@ def lower_distributed(roots: list[Node], d: int) -> list[Node]:
     def maybe_bcoo(x: Node) -> bool:
         # conservatively refuse operands the format pass could pin to
         # BCOO — shard_map specs assume dense global arrays
-        return (backend.HAS_SPARSE and len(x.shape) == 2
+        return (len(x.shape) == 2
                 and x.sparsity < SPARSE_THRESHOLD
                 and x.numel >= backend.SPARSE_MIN_NUMEL)
 

@@ -84,8 +84,6 @@ def _exec_sparsity(n: Node) -> float:
     behaviour identical across runtimes sharing one cache.
     """
     from . import backend
-    if not backend.HAS_SPARSE:
-        return 1.0
     cur = n
     while cur.op != "input":
         i = backend.bcoo_passthrough_arg(cur)
@@ -477,8 +475,7 @@ def leaf_row_bytes(n: Node) -> float:
     from . import backend
     itemsize = np.dtype(n.dtype).itemsize
     cols = n.shape[1] if len(n.shape) > 1 else 1
-    if backend.HAS_SPARSE and len(n.shape) == 2 \
-            and backend.leaf_format(n) == backend.BCOO:
+    if len(n.shape) == 2 and backend.leaf_format(n) == backend.BCOO:
         nse_per_row = float(cols) * max(n.sparsity, 1e-6)
         return max(nse_per_row * (itemsize + 8), 1.0)  # data + 2×int32
     return float(cols) * itemsize

@@ -80,8 +80,9 @@ KINDS = frozenset({
 
 class InjectedFault(RuntimeError):
     """A failure triggered by the active `FaultPlan`. Policy layers
-    catch this (and real exceptions) and run their recovery ladder;
-    with the policy off it propagates like any other error."""
+    catch this and run their recovery ladder (the site-RPC ladder also
+    catches real exceptions; the compile and prefetch ladders catch
+    only this); with the policy off nothing is injected."""
 
 
 class SiteFailedError(RuntimeError):
@@ -101,10 +102,11 @@ class SiteFailedError(RuntimeError):
 
 
 class CompileFailedError(RuntimeError):
-    """jit compilation of one segment failed. The segment executor
+    """An injected compile fault hit one segment. The segment executor
     catches this and falls back to the fuse=False interpreter for that
     segment; batched/sharded segments (no eager equivalent of the same
-    executable) re-raise."""
+    executable) re-raise. Genuine compile errors are never wrapped:
+    they propagate."""
 
     def __init__(self, seg_key: str, cause: BaseException):
         self.seg_key = seg_key

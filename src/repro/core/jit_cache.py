@@ -7,9 +7,7 @@ construction — share one XLA executable and replay without re-tracing.
 
 On a miss the segment closure is lowered ahead-of-time
 (`jax.jit(fn).lower(*args).compile()`) so trace+compile cost is measured
-explicitly and replay calls skip dispatch-time signature checks; if AOT
-lowering is unavailable for some input combination we fall back to the
-plain `jax.jit` wrapper (which still caches by aval internally).
+explicitly and replay calls skip dispatch-time signature checks.
 
 The cache is bounded: LRU eviction on BOTH an entry cap and a resident
 code-byte cap (executable size from XLA's `memory_analysis` when
@@ -30,13 +28,9 @@ from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 import jax
+from jax.experimental.sparse import BCOO as _BCOO
 
 from . import faults
-
-try:
-    from jax.experimental.sparse import BCOO as _BCOO
-except Exception:  # pragma: no cover
-    _BCOO = ()
 
 # Defaults for the process-wide cache; overridable per-process via env
 # so benchmark drivers / services can pin their own budget.
@@ -53,7 +47,6 @@ class JitCacheStats:
     hits: int = 0
     misses: int = 0
     trace_time: float = 0.0   # cumulative lower+compile seconds
-    aot_fallbacks: int = 0    # segments served by plain jit (AOT failed)
     evictions: int = 0        # entries dropped by the entry/byte caps
     bytes_cached: int = 0     # resident generated-code bytes (estimate)
     pinned: int = 0           # entries exempt from LRU (deploy-warmed)
@@ -61,7 +54,6 @@ class JitCacheStats:
     def as_dict(self) -> dict:
         return dict(hits=self.hits, misses=self.misses,
                     trace_time_s=round(self.trace_time, 6),
-                    aot_fallbacks=self.aot_fallbacks,
                     evictions=self.evictions,
                     bytes_cached=self.bytes_cached,
                     pinned=self.pinned)
@@ -79,7 +71,7 @@ def arg_signature(args) -> tuple:
     """
     out = []
     for a in args:
-        if _BCOO and isinstance(a, _BCOO):
+        if isinstance(a, _BCOO):
             # pytree flags are part of the aval too: an executable
             # compiled for unique_indices=True rejects a False-flagged
             # BCOO of identical shape/dtype/nse
@@ -176,26 +168,15 @@ class JitProgramCache:
         t0 = time.perf_counter()
         jitted = jax.jit(fn, donate_argnums=donate_argnums) \
             if donate_argnums else jax.jit(fn)
-        if hasattr(jitted, "lower"):
-            # Genuine trace/compile errors propagate immediately — masking
-            # them here would cache a broken wrapper that re-raises on
-            # every subsequent run with a misleading 'fallback' stat.
-            if donate_argnums:
-                # XLA warns when a donated buffer finds no same-
-                # shape/dtype output to alias — harmless (the buffer is
-                # dead either way) and would spam every compile
-                with warnings.catch_warnings():
-                    warnings.filterwarnings(
-                        "ignore", message=".*[Dd]onat.*")
-                    exe: Any = jitted.lower(*args).compile()
-            else:
-                exe = jitted.lower(*args).compile()
-        else:  # pragma: no cover - AOT API unavailable on this jax
-            warnings.warn("jax.jit(...).lower unavailable; segment will "
-                          "use dispatch-path jit", RuntimeWarning,
-                          stacklevel=2)
-            self.stats.aot_fallbacks += 1
-            exe = jitted
+        if donate_argnums:
+            # XLA warns when a donated buffer finds no same-shape/dtype
+            # output to alias — harmless (the buffer is dead either way)
+            # and would spam every compile
+            with warnings.catch_warnings():
+                warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
+                exe: Any = jitted.lower(*args).compile()
+        else:
+            exe = jitted.lower(*args).compile()
         dt = time.perf_counter() - t0
         self.stats.trace_time += dt
         nb = _exe_nbytes(exe)
@@ -264,7 +245,8 @@ class JitProgramCache:
         try:
             yield rec
         finally:
-            self._recorders.remove(rec)
+            # by identity: a nested block's set may equal this one
+            self._recorders = [r for r in self._recorders if r is not rec]
             for key in rec:
                 if key in self._entries:
                     self.pin(key)

@@ -711,15 +711,16 @@ class LineageRuntime:
                                                    jmesh=jmesh),
                         args, jcache, rctx=rctx, donate=don)
                 except CompileFailedError as e:
-                    # degradation ladder: a segment whose jit compile
-                    # failed runs its instructions eagerly through the
-                    # fuse=False kernels (parity by construction);
+                    # degradation ladder: a segment whose compile took
+                    # an injected fault runs its instructions eagerly
+                    # through the fuse=False kernels (parity by
+                    # construction);
                     # vmapped/sharded segments have no eager equivalent
                     # of the same executable and re-raise
                     e.args = (f"{e.args[0]} [{seg.summary()}]",)
                     if batched or seg_sharded:
                         raise
-                    outs = self._interpret_segment(seg, values, fmts, e)
+                    outs = self._interpret_segment(seg, values, fmts)
                 else:
                     if rctx.depth >= 2 and not seg_sharded and not (
                             batched and bctx.cshard > 1):
@@ -836,13 +837,12 @@ class LineageRuntime:
             try:
                 exe, dt_trace = jcache.compile(key, build_fn(), args,
                                                donate_argnums=donate)
-            except Exception as e:
+            except faults.InjectedFault as e:
                 # typed so the segment loop can take its degradation
-                # ladder (interpreter fallback); with the policy off
-                # compile errors propagate raw, as before
-                if faults.policy_enabled():
-                    raise CompileFailedError(seg_key, e) from e
-                raise
+                # ladder (interpreter fallback); a genuine compile error
+                # propagates, so a failing device compile is never
+                # hidden behind the interpreter
+                raise CompileFailedError(seg_key, e) from e
             self.stats.trace_time += dt_trace
         else:
             self.stats.jit_cache_hits += 1
@@ -883,7 +883,7 @@ class LineageRuntime:
                 raise
             # eager fallback computes ALL segment outputs; deliver only
             # the non-probe ones (the hit already filled probe_uid)
-            allouts = self._interpret_segment(seg, values, fmts, e)
+            allouts = self._interpret_segment(seg, values, fmts)
             by_uid = dict(zip(seg.output_uids, allouts, strict=True))
             outs = tuple(by_uid[u] for u in rest)
         # interpreter-equivalent accounting: it would execute every
@@ -894,8 +894,8 @@ class LineageRuntime:
 
     # ------------------------------------------------------------------
     def _interpret_segment(self, seg, values: dict[int, Any],
-                           fmts: dict, err: CompileFailedError) -> tuple:
-        """Graceful-degradation lane for a failed segment compile: run
+                           fmts: dict) -> tuple:
+        """Graceful-degradation lane for an injected compile fault: run
         the segment's instructions eagerly through `_exec_one` — the
         SAME kernels the fuse=False interpreter dispatches, so the
         degraded result matches the fused executable to numerical
@@ -903,8 +903,7 @@ class LineageRuntime:
         segment's declared outputs are returned."""
         flog = self.stats.faults
         flog.degradations += 1
-        if isinstance(err.cause, faults.InjectedFault):
-            flog.injected += 1
+        flog.injected += 1
         env = dict(values)  # shallow overlay: refs only
         for ins in seg.instructions:
             env[ins.out_id] = self._exec_one(ins, env, fmts)
@@ -1174,13 +1173,12 @@ class LineageRuntime:
         `CHUNK_LIVE_FACTOR` headroom for exactly this).
 
         A worker exception surfaces on the main thread at
-        `Future.result()`; under the fault policy the stream degrades
-        mid-flight to the synchronous chunk loop (injected or real
-        worker death costs the pipeline, never the answer), with the
-        policy off it propagates raw. Either way the `finally` cancels
-        queued preps (counted as `prefetch_cancelled`) and joins the
-        worker, so an error never leaves a hung thread or a
-        silently-dropped bucket."""
+        `Future.result()`. An injected fault degrades the stream
+        mid-flight to the synchronous chunk loop (it costs the
+        pipeline, never the answer); any other error propagates.
+        Either way the `finally` cancels queued preps (counted as
+        `prefetch_cancelled`) and joins the worker, so an error never
+        leaves a hung thread or a silently-dropped bucket."""
         log = self.stats.streaming
         plog = self.stats.pipeline
         # block-sum tables are only valid when the bound value IS the
@@ -1258,18 +1256,16 @@ class LineageRuntime:
                 if parts is None:
                     try:
                         args, live, dt_prep = fut.result()
-                    except Exception as err:
-                        if not faults.policy_enabled():
-                            raise
+                    except faults.InjectedFault:
                         # degradation ladder: the prefetch worker died
-                        # mid-stream — reclaim this span plus every
-                        # queued/unscheduled one and finish on the
-                        # synchronous loop (the `finally` still joins
-                        # the pool; the sync re-preps are
-                        # injection-free, so recovery terminates)
+                        # of an injected fault mid-stream — reclaim this
+                        # span plus every queued/unscheduled one and
+                        # finish on the synchronous loop (the `finally`
+                        # still joins the pool; the sync re-preps are
+                        # injection-free, so recovery terminates). A
+                        # genuine worker error propagates.
                         flog = self.stats.faults
-                        if isinstance(err, faults.InjectedFault):
-                            flog.injected += 1
+                        flog.injected += 1
                         flog.degradations += 1
                         tail = [(s, e, ckey, None)]
                         while inflight:

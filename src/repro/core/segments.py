@@ -388,10 +388,10 @@ def build_sharded_segment_fn(seg: Segment, formats: Optional[dict],
     axis, 'r' -> replicated). Collectives (`jax.lax.psum` inside the
     shard-reduce kernels, `all_gather` inside `reshard`) are the only
     cross-shard communication — exactly the exchanges the cost model
-    priced when it accepted the lowering. `check_rep=False`: psum
+    priced when it accepted the lowering. `check_vma=False`: psum
     outputs are replicated by construction.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.mesh import DATA_AXIS
@@ -403,7 +403,7 @@ def build_sharded_segment_fn(seg: Segment, formats: Optional[dict],
     in_specs = tuple(P(DATA_AXIS) if t == "s" else P() for t in in_tags)
     out_specs = tuple(P(DATA_AXIS) if t == "s" else P() for t in out_tags)
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 def build_config_sharded_segment_fn(seg: Segment, formats: Optional[dict],
@@ -416,7 +416,7 @@ def build_config_sharded_segment_fn(seg: Segment, formats: Optional[dict],
     configs are embarrassingly parallel; the stacked outputs reassemble
     along axis 0 via the out specs.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.mesh import CONFIG_AXIS
@@ -428,4 +428,4 @@ def build_config_sharded_segment_fn(seg: Segment, formats: Optional[dict],
     out_specs = tuple(P(CONFIG_AXIS) if u in batched_uids else P()
                       for u in out_uids)
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)

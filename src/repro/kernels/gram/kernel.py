@@ -27,6 +27,11 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BM = 512
 DEFAULT_BN = 256
+# f32 tiles (the DSL's float64 values, cast at the kernel boundary) must
+# multiply in f32: the MXU's default is one bf16 pass, which left 3e-4
+# relative error in lmDS's beta on a v5e. bf16 tiles take one pass
+# either way.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _gram_kernel(xi_ref, xj_ref, out_ref):
@@ -42,6 +47,7 @@ def _gram_kernel(xi_ref, xj_ref, out_ref):
         out_ref[...] += jax.lax.dot_general(
             xi_ref[...], xj_ref[...],
             dimension_numbers=(((0,), (0,)), ((), ())),  # contract over rows
+            precision=MXU_PRECISION,
             preferred_element_type=jnp.float32)
 
 
@@ -76,6 +82,7 @@ def _xtv_kernel(x_ref, v_ref, out_ref):
     out_ref[...] += jax.lax.dot_general(
         x_ref[...], v_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=MXU_PRECISION,
         preferred_element_type=jnp.float32)
 
 
@@ -89,11 +96,13 @@ def xtv_pallas(x: jnp.ndarray, v: jnp.ndarray, *, bm: int = DEFAULT_BM,
     out = pl.pallas_call(
         _xtv_kernel,
         grid=(n // bn, m // bm),
+        # block indices must be int32: a bare literal 0 traces as int64
+        # under x64, which Mosaic cannot lower
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, k: (k, i)),
-            pl.BlockSpec((bm, c), lambda i, k: (k, 0)),
+            pl.BlockSpec((bm, c), lambda i, k: (k, jnp.int32(0))),
         ],
-        out_specs=pl.BlockSpec((bn, c), lambda i, k: (i, 0)),
+        out_specs=pl.BlockSpec((bn, c), lambda i, k: (i, jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.float32),
         interpret=interpret,
     )(x, v)

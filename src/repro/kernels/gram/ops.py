@@ -17,10 +17,16 @@ from . import kernel, ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def _to_kernel_dtype(x: jnp.ndarray) -> jnp.ndarray:
+    """Cast a float64 operand to f32 for the Pallas path.
+
+    Mosaic takes no 64-bit operands, while the DSL computes in float64
+    (x64 is on process-wide). The kernels accumulate in f32 and return
+    f32 either way, so f32 tiles lose only input rounding."""
+    return x.astype(jnp.float32) if x.dtype == jnp.float64 else x
 
 
 def _pad_to(x: jnp.ndarray, bm: int, bn: int) -> jnp.ndarray:
@@ -53,7 +59,7 @@ def gram(x, *, use_pallas: Optional[bool] = None, interpret: bool = False,
     if not (use_pallas or interpret):
         return ref.gram(x)
     n = x.shape[1]
-    xp = _pad_to(x, bm, bn)
+    xp = _pad_to(_to_kernel_dtype(x), bm, bn)
     g = kernel.gram_pallas(xp, bm=bm, bn=bn, interpret=interpret)
     g = _mirror_upper(g, bn)
     return g[:n, :n]
@@ -74,8 +80,8 @@ def xtv(x, v, *, use_pallas: Optional[bool] = None, interpret: bool = False,
         return out[:, 0] if squeeze else out
     n, c = x.shape[1], v.shape[1]
     lane = 128
-    xp = _pad_to(x, bm, bn)
-    vp = _pad_to(v, bm, lane)
+    xp = _pad_to(_to_kernel_dtype(x), bm, bn)
+    vp = _pad_to(_to_kernel_dtype(v), bm, lane)
     out = kernel.xtv_pallas(xp, vp, bm=bm, bn=bn, interpret=interpret)
     out = out[:n, :c]
     return out[:, 0] if squeeze else out

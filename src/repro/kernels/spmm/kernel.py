@@ -32,12 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific grid spec; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gram.kernel import MXU_PRECISION
 
 DEFAULT_BM = 512
 DEFAULT_BN = 256
@@ -56,6 +53,7 @@ def _gram_kernel(mask_ref, xi_ref, xj_ref, out_ref):
         out_ref[...] += jax.lax.dot_general(
             xi_ref[...], xj_ref[...],
             dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=MXU_PRECISION,
             preferred_element_type=jnp.float32)
 
 
@@ -75,21 +73,12 @@ def gram_block_sparse(x: jnp.ndarray, mask: jnp.ndarray, *,
     ]
     out_spec = pl.BlockSpec((bn, bn), lambda i, j, k, *_: (i, j))
     out_shape = jax.ShapeDtypeStruct((n, n), jnp.float32)
-    if HAS_PLTPU:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
-            in_specs=in_specs, out_specs=out_spec)
-        return pl.pallas_call(_gram_kernel, grid_spec=grid_spec,
-                              out_shape=out_shape,
-                              interpret=interpret)(mask, x, x)
-    # pragma: no cover — pltpu unavailable; interpret-mode fallback where
-    # the mask rides along as a regular (whole-array) input
-    return pl.pallas_call(
-        _gram_kernel, grid=grid,
-        in_specs=[pl.BlockSpec(mask.shape, lambda i, j, k: (0, 0))]
-        + in_specs,
-        out_specs=out_spec, out_shape=out_shape,
-        interpret=True)(mask, x, x)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid,
+        in_specs=in_specs, out_specs=out_spec)
+    return pl.pallas_call(_gram_kernel, grid_spec=grid_spec,
+                          out_shape=out_shape,
+                          interpret=interpret)(mask, x, x)
 
 
 def _spmm_kernel(mask_ref, x_ref, w_ref, out_ref):
@@ -105,6 +94,7 @@ def _spmm_kernel(mask_ref, x_ref, w_ref, out_ref):
         out_ref[...] += jax.lax.dot_general(
             x_ref[...], w_ref[...],
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=MXU_PRECISION,
             preferred_element_type=jnp.float32)
 
 
@@ -118,24 +108,20 @@ def spmm_block_sparse(x: jnp.ndarray, w: jnp.ndarray, mask: jnp.ndarray, *,
     assert kdim == kw and m % bm == 0 and kdim % bk == 0, (x.shape, w.shape)
     assert mask.shape == (m // bm, kdim // bk)
     grid = (m // bm, kdim // bk)
+    # block indices must be int32: a bare literal 0 traces as int64
+    # under x64, which Mosaic cannot lower
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, k, *_: (i, k)),
-        pl.BlockSpec((bk, c), lambda i, k, *_: (k, 0)),
+        pl.BlockSpec((bk, c), lambda i, k, *_: (k, jnp.int32(0))),
     ]
-    out_spec = pl.BlockSpec((bm, c), lambda i, k, *_: (i, 0))
+    out_spec = pl.BlockSpec((bm, c), lambda i, k, *_: (i, jnp.int32(0)))
     out_shape = jax.ShapeDtypeStruct((m, c), jnp.float32)
-    if HAS_PLTPU:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
-            in_specs=in_specs, out_specs=out_spec)
-        return pl.pallas_call(_spmm_kernel, grid_spec=grid_spec,
-                              out_shape=out_shape,
-                              interpret=interpret)(mask, x, w)
-    return pl.pallas_call(  # pragma: no cover — see gram_block_sparse
-        _spmm_kernel, grid=grid,
-        in_specs=[pl.BlockSpec(mask.shape, lambda i, k: (0, 0))] + in_specs,
-        out_specs=out_spec, out_shape=out_shape,
-        interpret=True)(mask, x, w)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid,
+        in_specs=in_specs, out_specs=out_spec)
+    return pl.pallas_call(_spmm_kernel, grid_spec=grid_spec,
+                          out_shape=out_shape,
+                          interpret=interpret)(mask, x, w)
 
 
 def _xtv_kernel(mask_ref, x_ref, v_ref, out_ref):
@@ -151,6 +137,7 @@ def _xtv_kernel(mask_ref, x_ref, v_ref, out_ref):
         out_ref[...] += jax.lax.dot_general(
             x_ref[...], v_ref[...],
             dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=MXU_PRECISION,
             preferred_element_type=jnp.float32)
 
 
@@ -166,19 +153,13 @@ def xtv_block_sparse(x: jnp.ndarray, v: jnp.ndarray, mask: jnp.ndarray, *,
     grid = (n // bn, m // bm)
     in_specs = [
         pl.BlockSpec((bm, bn), lambda i, k, *_: (k, i)),
-        pl.BlockSpec((bm, c), lambda i, k, *_: (k, 0)),
+        pl.BlockSpec((bm, c), lambda i, k, *_: (k, jnp.int32(0))),
     ]
-    out_spec = pl.BlockSpec((bn, c), lambda i, k, *_: (i, 0))
+    out_spec = pl.BlockSpec((bn, c), lambda i, k, *_: (i, jnp.int32(0)))
     out_shape = jax.ShapeDtypeStruct((n, c), jnp.float32)
-    if HAS_PLTPU:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
-            in_specs=in_specs, out_specs=out_spec)
-        return pl.pallas_call(_xtv_kernel, grid_spec=grid_spec,
-                              out_shape=out_shape,
-                              interpret=interpret)(mask, x, v)
-    return pl.pallas_call(  # pragma: no cover — see gram_block_sparse
-        _xtv_kernel, grid=grid,
-        in_specs=[pl.BlockSpec(mask.shape, lambda i, k: (0, 0))] + in_specs,
-        out_specs=out_spec, out_shape=out_shape,
-        interpret=True)(mask, x, v)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid,
+        in_specs=in_specs, out_specs=out_spec)
+    return pl.pallas_call(_xtv_kernel, grid_spec=grid_spec,
+                          out_shape=out_shape,
+                          interpret=interpret)(mask, x, v)

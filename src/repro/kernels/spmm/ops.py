@@ -20,7 +20,8 @@ import jax.numpy as jnp
 # shared with the dense gram kernel family: backend detection, block
 # padding, and the upper-triangle mirror must not drift between the
 # dense and block-sparse paths
-from repro.kernels.gram.ops import _mirror_upper, _on_tpu, _pad_to
+from repro.kernels.gram.ops import (_mirror_upper, _on_tpu, _pad_to,
+                                    _to_kernel_dtype)
 
 from . import kernel
 
@@ -37,7 +38,7 @@ def gram_dense_masked(xd: jnp.ndarray, *, bm: int = kernel.DEFAULT_BM,
                       interpret: bool = False) -> jnp.ndarray:
     """Block-masked gram over a dense-layout matrix (the TPU path)."""
     n = xd.shape[1]
-    xp = _pad_to(xd, bm, bn)
+    xp = _pad_to(_to_kernel_dtype(xd), bm, bn)
     mask = block_mask(xp, bm, bn)
     g = kernel.gram_block_sparse(xp, mask, bm=bm, bn=bn,
                                  interpret=interpret)
@@ -51,8 +52,8 @@ def spmm_dense_masked(xd: jnp.ndarray, w: jnp.ndarray, *,
     """Block-masked X @ W over a dense-layout X (the TPU path)."""
     m, c = xd.shape[0], w.shape[1]
     lane = 128
-    xp = _pad_to(xd, bm, bk)
-    wp = _pad_to(w, bk, lane)
+    xp = _pad_to(_to_kernel_dtype(xd), bm, bk)
+    wp = _pad_to(_to_kernel_dtype(w), bk, lane)
     mask = block_mask(xp, bm, bk)
     out = kernel.spmm_block_sparse(xp, wp, mask, bm=bm, bk=bk,
                                    interpret=interpret)
@@ -66,8 +67,8 @@ def xtv_dense_masked(xd: jnp.ndarray, v: jnp.ndarray, *,
     """Block-masked X^T v over a dense-layout X (the TPU path)."""
     n, c = xd.shape[1], v.shape[1]
     lane = 128
-    xp = _pad_to(xd, bm, bn)
-    vp = _pad_to(v, bm, lane)
+    xp = _pad_to(_to_kernel_dtype(xd), bm, bn)
+    vp = _pad_to(_to_kernel_dtype(v), bm, lane)
     mask = block_mask(xp, bm, bn)
     out = kernel.xtv_block_sparse(xp, vp, mask, bm=bm, bn=bn,
                                   interpret=interpret)

@@ -7,19 +7,11 @@ to control initialization order.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 explicit-sharding API; older jax has no AxisType
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    _AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -111,10 +111,7 @@ def moe_forward_ep(p: Params, cfg, x: jnp.ndarray
     cfg.capacity_factor bounds memory (Switch-style token dropping,
     overflow slots masked).
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.hints import _STATE, current_mesh
 
@@ -203,10 +200,7 @@ def moe_forward_ep(p: Params, cfg, x: jnp.ndarray
         in_specs=(P(dpa, None), P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(dpa, None), P()))
-    try:
-        wrapped = shard_map(rank_fn, check_vma=False, **sm_kwargs)
-    except TypeError:  # older jax spelling
-        wrapped = shard_map(rank_fn, check_rep=False, **sm_kwargs)
+    wrapped = shard_map(rank_fn, check_vma=False, **sm_kwargs)
     out, aux = wrapped(xf, router, experts["w_gate"], experts["w_up"],
                        experts["w_down"])
 

@@ -16,10 +16,6 @@ from repro.core.compiler import compile_plan
 from repro.core.dag import SPARSE_THRESHOLD
 from repro.core.rewrites import run_rewrites
 
-needs_sparse = pytest.mark.skipif(not backend.HAS_SPARSE,
-                                  reason="jax.experimental.sparse absent")
-
-
 def _sparse_mat(rng, m, n, density):
     return rng.normal(size=(m, n)) * (rng.random((m, n)) < density)
 
@@ -28,7 +24,6 @@ def _sparse_mat(rng, m, n, density):
 # format assignment
 # ---------------------------------------------------------------------------
 
-@needs_sparse
 class TestFormatAssignment:
     def test_sparse_leaf_assigned_bcoo(self, rng):
         x = input_tensor("Xs", _sparse_mat(rng, 128, 64, 0.05))
@@ -112,7 +107,6 @@ def _registry_pipeline(x, y):
             ops.sum_(stacked), cat, ops.sqrt(ops.abs_(g)) + g * g]
 
 
-@needs_sparse
 class TestDenseSparseParity:
     @pytest.mark.parametrize("density", [0.01, 0.05, 0.2])
     @pytest.mark.parametrize("fuse", [True, False])
@@ -215,7 +209,6 @@ class TestSpmmKernels:
 # sparse cache accounting (reuse.nbytes)
 # ---------------------------------------------------------------------------
 
-@needs_sparse
 class TestSparseCacheAccounting:
     def test_bcoo_nbytes_is_sparse_size(self, rng):
         from jax.experimental import sparse as jsparse

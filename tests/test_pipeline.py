@@ -15,10 +15,11 @@ The contract under test:
     bindings, reuse-cache entries and probe-hit values survive any
     number of donating runs, and donated executables live under a
     separate `|don:`-suffixed jit-cache key;
-  * a prefetch-worker error is absorbed by the fault policy — the
-    worker is joined and the stream finishes on the synchronous chunk
-    loop with the exact answer (`REPRO_FAULT_POLICY=off` restores raw
-    propagation) — no hung threads, no silently dropped buckets; the
+  * an injected prefetch-worker fault is absorbed by the fault policy
+    — the worker is joined and the stream finishes on the synchronous
+    chunk loop with the exact answer; a genuine worker error (and any
+    error with `REPRO_FAULT_POLICY=off`) propagates — no hung threads,
+    no silently dropped buckets; the
     serving completion worker keeps `QueueFullError` backpressure
     working while a batch is in flight.
 """
@@ -28,7 +29,7 @@ import time
 import numpy as np
 import pytest
 
-from repro.core import costmodel, ops, runtime as rt_mod
+from repro.core import costmodel, faults, ops, runtime as rt_mod
 from repro.core.dag import input_tensor
 from repro.core.jit_cache import get_jit_cache
 from repro.core.reuse import ReuseCache
@@ -266,27 +267,29 @@ def test_batched_dispatches_never_donate(rng, monkeypatch):
 # prefetch-worker error propagation
 # ---------------------------------------------------------------------------
 
-def _boom_on_prefetch(real):
+def _boom_on_prefetch(real, exc=RuntimeError):
     def boom(a):
         if threading.current_thread().name.startswith("chunk-prefetch"):
-            raise RuntimeError("prefetch boom")
+            raise exc("prefetch boom")
         return real(a)
     return boom
 
 
 def test_prefetch_error_degrades_to_sync_tail(rng, monkeypatch):
-    # Under the default fault policy a prefetch-worker crash is not
-    # fatal: the runtime cancels queued preps, joins the worker, and
-    # finishes the stream on the synchronous chunk loop (PR 10).
+    # Under the default fault policy an injected prefetch-worker crash
+    # is not fatal: the runtime cancels queued preps, joins the worker,
+    # and finishes the stream on the synchronous chunk loop.
     monkeypatch.setattr(costmodel, "CHUNK_MEM_BUDGET", BUDGET)
     monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "2")
     real = rt_mod._reuse_nbytes
-    monkeypatch.setattr(rt_mod, "_reuse_nbytes", _boom_on_prefetch(real))
+    monkeypatch.setattr(rt_mod, "_reuse_nbytes",
+                        _boom_on_prefetch(real, faults.InjectedFault))
     Xh, yh = rng.normal(size=(4096, 8)), rng.normal(size=(4096,))
     rt = LineageRuntime(cache=None, fuse=True)
     got = _lm_run(rt, Xh, yh)
     assert np.abs(got - _lm_ref(Xh, yh).ravel()).max() < 1e-10
     assert rt.stats.faults.degradations == 1
+    assert rt.stats.faults.injected == 1
     # clean shutdown: queued preps cancelled, worker joined
     assert _no_prefetch_threads()
     # and the runtime is not poisoned: the next run (healthy worker)
@@ -295,6 +298,26 @@ def test_prefetch_error_degrades_to_sync_tail(rng, monkeypatch):
     again = _lm_run(rt, Xh, yh)
     assert np.abs(again - _lm_ref(Xh, yh).ravel()).max() < 1e-10
     assert rt.stats.faults.degradations == 1  # healthy run added none
+    assert _no_prefetch_threads()
+
+
+def test_genuine_prefetch_error_propagates(rng, monkeypatch):
+    # Only an injected fault takes the prefetch ladder: a genuine worker
+    # error propagates under the default policy too, the worker is
+    # joined, and the runtime streams again on the next run.
+    monkeypatch.setattr(costmodel, "CHUNK_MEM_BUDGET", BUDGET)
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "2")
+    real = rt_mod._reuse_nbytes
+    monkeypatch.setattr(rt_mod, "_reuse_nbytes", _boom_on_prefetch(real))
+    Xh, yh = rng.normal(size=(4096, 8)), rng.normal(size=(4096,))
+    rt = LineageRuntime(cache=None, fuse=True)
+    with pytest.raises(RuntimeError, match="prefetch boom"):
+        _lm_run(rt, Xh, yh)
+    assert rt.stats.faults.degradations == 0
+    assert _no_prefetch_threads()
+    monkeypatch.setattr(rt_mod, "_reuse_nbytes", real)
+    got = _lm_run(rt, Xh, yh)
+    assert np.abs(got - _lm_ref(Xh, yh).ravel()).max() < 1e-10
     assert _no_prefetch_threads()
 
 

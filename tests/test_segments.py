@@ -261,3 +261,27 @@ class TestJitExecutableCache:
         rt.evaluate([ops.gram(x) + ops.eye(6)])
         assert rt.stats.segments >= 1
         assert rt.stats.instructions == rt.stats.executed > 0
+
+    def test_genuine_compile_error_propagates(self, rng, monkeypatch):
+        # Only an injected compile fault may take the interpreter ladder:
+        # a segment that truly fails to compile (here a gram kernel whose
+        # contraction shapes disagree, refused while the segment is
+        # traced for its compile) must fail the run, not degrade.
+        import jax.numpy as jnp
+
+        from repro.core import backend
+
+        def broken_gram(attrs):
+            return lambda x: jnp.dot(backend.densify(x), jnp.ones((3, 3)))
+
+        backend._kernel_cached.cache_clear()
+        monkeypatch.setitem(backend._KERNEL_BUILDERS, "gram", broken_gram)
+        try:
+            rt = LineageRuntime(fuse=True)
+            x = input_tensor("X", rng.normal(size=(37, 5)))
+            y = input_tensor("y", rng.normal(size=(37, 1)))
+            with pytest.raises(TypeError):
+                rt.evaluate([_ridge(x, y)])
+            assert rt.stats.faults.degradations == 0
+        finally:
+            backend._kernel_cached.cache_clear()

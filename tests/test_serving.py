@@ -156,6 +156,18 @@ class TestJitCachePinning:
         assert cache.stats.pinned == 0
         assert len(cache) == 1          # caps re-applied on unpin
 
+    def test_nested_pinning_blocks(self):
+        # an outer block (a caller recording what a deploy runs) around
+        # an inner one that records the same keys: each block closes its
+        # own recorder, and both pin what they saw
+        cache = JitProgramCache()
+        with cache.pinning() as outer:
+            with cache.pinning() as inner:
+                self._fill(cache, 2)
+            assert inner == outer
+        assert len(outer) == 2 and cache.stats.pinned == 2
+        assert cache._recorders == []
+
     def test_clear_drops_pins(self):
         cache = JitProgramCache()
         with cache.pinning():
