@@ -111,7 +111,7 @@ def _append_lane(rows: int, cols: int, budget_ratio: int, repeats: int,
         f"synchronous path (>= {min_speedup}x required)"
     pdict = p.as_dict()
     return dict(budget=budget, t_sync=t_sync, t_pipe=t_pipe,
-                speedup=speedup, overlap_ratio=pdict["overlap_ratio"],
+                speedup=speedup,
                 prefetch_issued=pdict["prefetch_issued"],
                 prefetch_hits=pdict["prefetch_hits"],
                 donated_buffers=pdict["donated_buffers"],
@@ -167,8 +167,7 @@ def main(rows: int = 131072, cols: int = 256, budget_ratio: int = 10,
 
     emit("pipeline_append_retrain", app["t_pipe"],
          f"sync_us={app['t_sync']*1e6:.0f};"
-         f"speedup={app['speedup']:.2f}x;"
-         f"overlap={app['overlap_ratio']:.2f}")
+         f"speedup={app['speedup']:.2f}x")
     emit("pipeline_serving_openloop", srv["run"]["p50_us"] * 1e-6,
          f"qps={srv['run']['qps']:.0f};rebatches={srv['rebatches']};"
          f"idle_frac={srv['run']['idle_frac']:.2f}")
@@ -181,7 +180,6 @@ def main(rows: int = 131072, cols: int = 256, budget_ratio: int = 10,
         append_sync_us_per_call=round(app["t_sync"] * 1e6, 1),
         append_pipelined_us_per_call=round(app["t_pipe"] * 1e6, 1),
         append_speedup=round(app["speedup"], 2),
-        overlap_ratio=app["overlap_ratio"],
         prefetch_issued=app["prefetch_issued"],
         prefetch_hits=app["prefetch_hits"],
         donated_buffers=app["donated_buffers"],

@@ -110,7 +110,7 @@ def aggregate() -> None:
                 # streaming residency columns (BENCH_streaming)
                 or k.endswith("chunks") or k == "peak_live_bytes"
                 # async-pipeline columns (BENCH_pipeline)
-                or k == "overlap_ratio" or k == "rebatches"
+                or k == "rebatches"
                 or k == "donated_buffers"
                 # fault-tolerance columns (BENCH_faults)
                 or k == "incidents" or k.endswith("_overhead_pct"))

@@ -84,8 +84,8 @@ def _open_loop(server, d: int, rate_qps: float, n: int, seed: int) -> dict:
     its scheduled arrival regardless of completions (no coordinated
     omission); reports per-request latency percentiles, sustained QPS
     over the span from first arrival to last completion, and queue-idle
-    time — span minus dispatch-stage busy seconds
-    (`ServingLog.busy_s`), i.e. how much headroom the request path
+    time — span minus dispatch-stage busy seconds (the runtime's
+    `ds.serve.batch` span), i.e. how much headroom the request path
     still has at this offered rate."""
     rng = np.random.default_rng(seed)
     gaps = rng.exponential(1.0 / rate_qps, size=n)
@@ -93,7 +93,8 @@ def _open_loop(server, d: int, rate_qps: float, n: int, seed: int) -> dict:
     rows = [rng.normal(size=(1, d)) for _ in range(n)]
     lat_us = [0.0] * n
     done_at = [0.0] * n
-    busy0 = server.runtime.stats.serving.busy_s
+    spans = server.runtime.stats.spans
+    busy0 = spans.s("ds.serve.batch")
     start = time.perf_counter() + 0.05   # common epoch for all threads
 
     def fire(i):
@@ -113,7 +114,7 @@ def _open_loop(server, d: int, rate_qps: float, n: int, seed: int) -> dict:
         t.join()
     span = max(done_at) - (start + float(arrivals[0]))
     p50, p99 = np.percentile(lat_us, [50, 99])
-    busy = server.runtime.stats.serving.busy_s - busy0
+    busy = spans.s("ds.serve.batch") - busy0
     idle = max(span - busy, 0.0)
     return dict(rate=rate_qps, n=n, p50_us=float(p50), p99_us=float(p99),
                 qps=n / span, busy_s=float(busy),

@@ -132,8 +132,11 @@ def main(rows: int = 1024, cols: int = 64, calls: int = 20,
         f"fused reuse diverged from interpreter: {hits_f} vs {hits_i}"
     assert shape["instruction_count"] > 2 * shape["segment_count"], \
         f"reuse-active plan failed to fuse: {shape}"
-    emit("sparse_reuse_fusion",
-         rs_f["exec_time_s"] / max(rs_f["segments"], 1),
+    # host seconds dispatching and waiting on the fused segments
+    sp = rs_f["spans"]
+    run_s = sum(sp.get(k, {}).get("s", 0.0)
+                for k in ("ds.dispatch", "ds.wait"))
+    emit("sparse_reuse_fusion", run_s / max(rs_f["segments"], 1),
          f"instr={shape['instruction_count']};"
          f"segments={shape['segment_count']};hits={rc_f['hits']}")
 

@@ -180,8 +180,9 @@ class Smoke:
         st = rt.stats.as_dict()
         st.pop("faults", None)
         log(f"  smoke timing (cold run, not a benchmark): "
-            f"{time.perf_counter() - t0:.3f} s, compile "
-            f"{rt.stats.trace_time:.3f} s")
+            f"{time.perf_counter() - t0:.3f} s, "
+            f"{rt.stats.compiles} compiles in "
+            f"{rt.stats.spans.s('ds.jit.compile'):.3f} s")
         log(f"  counters: {json.dumps(st, default=str)}")
 
     # -- phase a ----------------------------------------------------------

@@ -40,6 +40,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from .tracing import no_span, segment_fn_name
+
 
 @dataclass
 class ExchangeLog:
@@ -117,8 +119,9 @@ class LocalSite:
         cache key). Per-site sub-segments share warm executables across
         sites/runs whenever (op, attrs, arg signature) match. `stats`
         (a `RuntimeStats`) receives the same accounting the fused
-        segment executor books: compile seconds into `trace_time`, warm
-        lookups into `jit_cache_hits`.
+        segment executor books: misses into `compiles` and the
+        `ds.jit.compile` span, warm lookups into `jit_cache_hits`, the
+        call into `ds.dispatch` and its device sync into `ds.wait`.
 
         `vmap_axes` (batched `parfor` grids) maps the kernel over a
         leading config axis of the flagged operands (`jax.vmap`
@@ -142,19 +145,27 @@ class LocalSite:
         seg_key = f"fedsite|{op}|{attrs!r}"
         if vmap_axes is not None:
             seg_key += f"|vmap:{vmap_axes!r}"
+        span = stats.spans if stats is not None else no_span
         key, exe = cache.lookup(seg_key, args)
         if exe is None:
             kern = backend.get_kernel(op, dict(attrs))
-            fn = lambda *xs: (kern(*xs),)  # noqa: E731
+
+            def fn(*xs):
+                return (kern(*xs),)
+            # the XLA module (and the device trace) reads ds_fed_<op>
+            fn.__name__ = fn.__qualname__ = segment_fn_name(("fed", op))
             if vmap_axes is not None:
                 fn = jax.vmap(fn, in_axes=vmap_axes, out_axes=0)
-            exe, dt = cache.compile(key, fn, args)
+            with span("ds.jit.compile"):
+                exe = cache.compile(key, fn, args)
             if stats is not None:
-                stats.trace_time += dt
+                stats.compiles += 1
         elif stats is not None:
             stats.jit_cache_hits += 1
-        out = exe(*args)[0]
-        backend.block_ready(out)
+        with span("ds.dispatch"):
+            out = exe(*args)[0]
+        with span("ds.wait"):
+            backend.block_ready(out)
         return out
 
     # -- eager numpy oracle -------------------------------------------------

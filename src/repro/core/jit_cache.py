@@ -6,8 +6,9 @@ Keyed by (segment canonical structural key, concrete input signature
 construction — share one XLA executable and replay without re-tracing.
 
 On a miss the segment closure is lowered ahead-of-time
-(`jax.jit(fn).lower(*args).compile()`) so trace+compile cost is measured
-explicitly and replay calls skip dispatch-time signature checks.
+(`jax.jit(fn).lower(*args).compile()`), so replay calls skip
+dispatch-time signature checks; the runtime books that compile as its
+`ds.jit.compile` span (`repro.core.tracing`).
 
 The cache is bounded: LRU eviction on BOTH an entry cap and a resident
 code-byte cap (executable size from XLA's `memory_analysis` when
@@ -20,7 +21,6 @@ stay at a bounded footprint, and eviction/hit/miss counters surface in
 from __future__ import annotations
 
 import os
-import time
 import warnings
 from collections import OrderedDict
 from contextlib import contextmanager
@@ -46,14 +46,12 @@ FALLBACK_EXE_BYTES = 64 << 10
 class JitCacheStats:
     hits: int = 0
     misses: int = 0
-    trace_time: float = 0.0   # cumulative lower+compile seconds
     evictions: int = 0        # entries dropped by the entry/byte caps
     bytes_cached: int = 0     # resident generated-code bytes (estimate)
     pinned: int = 0           # entries exempt from LRU (deploy-warmed)
 
     def as_dict(self) -> dict:
         return dict(hits=self.hits, misses=self.misses,
-                    trace_time_s=round(self.trace_time, 6),
                     evictions=self.evictions,
                     bytes_cached=self.bytes_cached,
                     pinned=self.pinned)
@@ -152,9 +150,9 @@ class JitProgramCache:
         return key, None
 
     def compile(self, key: tuple, fn: Callable, args,
-                donate_argnums: tuple = ()) -> tuple[Callable, float]:
-        """Compile `fn` for `args`, store under `key`; returns
-        (executable, trace_seconds).
+                donate_argnums: tuple = ()) -> Callable:
+        """Compile `fn` for `args`, store under `key`; returns the
+        executable.
 
         `donate_argnums` marks dead-after-segment arguments whose
         buffers XLA may alias into the outputs (the async pipeline's
@@ -165,7 +163,6 @@ class JitProgramCache:
         # call before any tracing happens — callers degrade to the
         # interpreter (segments) or retry (site sub-segments)
         faults.compile_entry(key[0] if isinstance(key, tuple) else key)
-        t0 = time.perf_counter()
         jitted = jax.jit(fn, donate_argnums=donate_argnums) \
             if donate_argnums else jax.jit(fn)
         if donate_argnums:
@@ -177,8 +174,6 @@ class JitProgramCache:
                 exe: Any = jitted.lower(*args).compile()
         else:
             exe = jitted.lower(*args).compile()
-        dt = time.perf_counter() - t0
-        self.stats.trace_time += dt
         nb = _exe_nbytes(exe)
         old = self._entries.pop(key, None)
         if old is not None:  # racing recompile of the same key
@@ -186,7 +181,7 @@ class JitProgramCache:
         self._entries[key] = (exe, nb)
         self.stats.bytes_cached += nb
         self._evict()
-        return exe, dt
+        return exe
 
     def _evict(self) -> None:
         # Walk LRU-first, skipping pinned entries — pinned executables

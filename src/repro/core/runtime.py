@@ -38,6 +38,7 @@ from .federated import ExchangeLog, FederatedTensor, LocalSite
 from .jit_cache import get_jit_cache
 from .reuse import ReuseCache
 from .reuse import nbytes as _reuse_nbytes
+from .tracing import SpanLog
 
 
 @dataclass
@@ -118,10 +119,6 @@ class ServingLog:
     # stay 0 in steady state, and the serving benchmark asserts it.
     retraces: int = 0
     queue_wait_s: float = 0.0  # total enqueue->dispatch delay
-    # seconds the dispatch stage spent replaying batches — open-loop
-    # benchmarks subtract this from wall span to report queue-idle time
-    # (how much headroom the request path has at a given arrival rate)
-    busy_s: float = 0.0
 
     @property
     def total(self) -> int:
@@ -132,8 +129,7 @@ class ServingLog:
                    max_coalesce=self.max_coalesce, padded=self.padded,
                    queue_peak=self.queue_peak, rejected=self.rejected,
                    retraces=self.retraces,
-                   queue_wait_s=round(self.queue_wait_s, 6),
-                   busy_s=round(self.busy_s, 6))
+                   queue_wait_s=round(self.queue_wait_s, 6))
         if self.batches:
             out["mean_coalesce"] = round(self.requests / self.batches, 2)
         return out
@@ -145,19 +141,13 @@ class PipelineLog:
     closes ROADMAP items 1/2/4's carried "Remaining" bullets).
 
     At pipeline depth >= 2 the segment executor stops syncing the
-    device at segment boundaries: `dispatch_s` is host time spent
-    *issuing* executables (XLA computes in the background), `block_s`
-    is host time actually blocked materializing results at plan roots /
-    probe points / host-op boundaries, and `prefetch_s` is worker time
-    spent prepping streaming buckets concurrently with device compute.
-    All counters stay 0 at depth 1 (`REPRO_PIPELINE_DEPTH=1`), which is
-    what keeps the depth-1 `as_dict()` bitwise-identical to the
-    pre-pipeline runtime."""
+    device at segment boundaries. These are counts; the seconds of
+    issuing dispatches, blocking on results and prepping buckets are
+    the `ds.dispatch`, `ds.wait` and `ds.stream.prep` spans
+    (`RuntimeStats.spans`). All counters stay 0 at depth 1
+    (`REPRO_PIPELINE_DEPTH=1`)."""
 
     async_segments: int = 0   # dispatches returned without a device sync
-    dispatch_s: float = 0.0   # host seconds issuing async dispatches
-    block_s: float = 0.0      # host seconds blocked materializing results
-    prefetch_s: float = 0.0   # worker seconds prepping buckets (overlapped)
     prefetch_issued: int = 0  # bucket preps handed to the worker
     prefetch_hits: int = 0    # prepped buckets consumed by a dispatch
     prefetch_cancelled: int = 0  # prepped buckets discarded (cache hit
@@ -173,23 +163,13 @@ class PipelineLog:
                 + self.donated_buffers + self.rebatches)
 
     def as_dict(self) -> dict:
-        out = dict(async_segments=self.async_segments,
-                   dispatch_s=round(self.dispatch_s, 6),
-                   block_s=round(self.block_s, 6),
-                   prefetch_s=round(self.prefetch_s, 6),
-                   prefetch_issued=self.prefetch_issued,
-                   prefetch_hits=self.prefetch_hits,
-                   prefetch_cancelled=self.prefetch_cancelled,
-                   donated_buffers=self.donated_buffers,
-                   donated_bytes=self.donated_bytes,
-                   rebatches=self.rebatches)
-        # share of pipeline host time spent on useful (overlappable)
-        # work — issuing dispatches and prepping buckets — vs blocked
-        # waiting on the device; 1.0 means the host never waited
-        busy = self.dispatch_s + self.prefetch_s
-        wall = busy + self.block_s
-        out["overlap_ratio"] = round(busy / wall, 4) if wall > 0 else 0.0
-        return out
+        return dict(async_segments=self.async_segments,
+                    prefetch_issued=self.prefetch_issued,
+                    prefetch_hits=self.prefetch_hits,
+                    prefetch_cancelled=self.prefetch_cancelled,
+                    donated_buffers=self.donated_buffers,
+                    donated_bytes=self.donated_bytes,
+                    rebatches=self.rebatches)
 
 
 @dataclass
@@ -197,11 +177,14 @@ class RuntimeStats:
     instructions: int = 0
     executed: int = 0      # instructions actually computed (not reused)
     reused: int = 0
-    exec_time: float = 0.0
     segments: int = 0        # segments dispatched on the fused path
     batched_segments: int = 0  # config-variant segments run under vmap
     jit_cache_hits: int = 0  # warm compiled-executable lookups
-    trace_time: float = 0.0  # seconds spent tracing+compiling segments
+    compiles: int = 0        # executables compiled (jit-cache misses,
+                             # federated site sub-segments included)
+    # host seconds and counts per layer boundary (`ds.*` spans, on the
+    # profiler's clock while one runs) — see repro.core.tracing
+    spans: SpanLog = field(default_factory=SpanLog)
     # bytes crossing the federation boundary (fed_* / collect
     # instructions), metered per site — the §3.3 "exchange constraints"
     # as an auditable budget. Identical across fuse modes by
@@ -230,11 +213,10 @@ class RuntimeStats:
 
     def as_dict(self):
         out = dict(instructions=self.instructions, executed=self.executed,
-                   reused=self.reused, exec_time_s=round(self.exec_time, 6),
-                   segments=self.segments,
+                   reused=self.reused, segments=self.segments,
                    batched_segments=self.batched_segments,
                    jit_cache_hits=self.jit_cache_hits,
-                   trace_time_s=round(self.trace_time, 6))
+                   compiles=self.compiles, spans=self.spans.as_dict())
         if self.exchange.total:
             out["exchange"] = self.exchange.as_dict()
         if self.shard.total:
@@ -328,9 +310,10 @@ class LineageRuntime:
 
     # ------------------------------------------------------------------
     def evaluate(self, outputs: Sequence[LTensor]) -> list[np.ndarray]:
-        plan = compile_plan(list(outputs),
-                            reuse_enabled=self.cache is not None,
-                            opt_level=self.opt_level)
+        with self.stats.spans("ds.compile_plan"):
+            plan = compile_plan(list(outputs),
+                                reuse_enabled=self.cache is not None,
+                                opt_level=self.opt_level)
         return self.run_plan(plan)
 
     # ------------------------------------------------------------------
@@ -339,20 +322,16 @@ class LineageRuntime:
                  leaf_lineage: Optional[dict[int, str]] = None) -> list[np.ndarray]:
         values, lin = self._bind_leaves(plan, leaf_values, leaf_lineage)
         if self.fuse:
-            rctx = _RunCtx(depth=costmodel.pipeline_depth())
-            self._run_segments(plan, values, lin, rctx=rctx)
-            if rctx.depth >= 2:
-                # plan roots are THE sync point of the async pipeline:
-                # segment dispatches above returned without blocking,
-                # so the whole device backlog drains here, metered
-                t0 = time.perf_counter()
-                outs = [backend.to_numpy(values[i])
-                        for i in plan.output_ids]
-                self.stats.pipeline.block_s += time.perf_counter() - t0
-                return outs
+            self._run_segments(
+                plan, values, lin,
+                rctx=_RunCtx(depth=costmodel.pipeline_depth()))
         else:
             self._run_instructions(plan, values, lin)
-        return [backend.to_numpy(values[i]) for i in plan.output_ids]
+        # plan roots are THE sync point of the async pipeline (depth
+        # >= 2): segment dispatches returned without blocking, so the
+        # whole device backlog drains here
+        with self.stats.spans("ds.wait"):
+            return [backend.to_numpy(values[i]) for i in plan.output_ids]
 
     # ------------------------------------------------------------------
     def evaluate_batch(self, bplan) -> list[list[np.ndarray]]:
@@ -388,28 +367,18 @@ class LineageRuntime:
         values, lin = self._bind_leaves(plan, leaf_values, None)
         rctx = _RunCtx(depth=costmodel.pipeline_depth())
         self._run_segments(plan, values, lin, bctx=bctx, rctx=rctx)
-        return self._unpack_batch(plan, values, bctx, rctx=rctx)
+        with self.stats.spans("ds.wait"):
+            return self._unpack_batch(plan, values, bctx)
 
     # ------------------------------------------------------------------
-    def _unpack_batch(self, plan: Plan, values: dict[int, Any],
-                      bctx: _BatchCtx,
-                      rctx: Optional[_RunCtx] = None
-                      ) -> list[list[np.ndarray]]:
+    @staticmethod
+    def _unpack_batch(plan: Plan, values: dict[int, Any],
+                      bctx: _BatchCtx) -> list[list[np.ndarray]]:
         """Split a batched run's outputs into one list per config, in
         order, with the bucket padding sliced off. At pipeline depth
         >= 2 this is the batched path's sync point: the invariant
         prefix and the vmapped variant suffix were all dispatched
         without blocking, and the device backlog drains here."""
-        t0 = time.perf_counter() if rctx is not None \
-            and rctx.depth >= 2 else None
-        out = self._unpack_batch_sync(plan, values, bctx)
-        if t0 is not None:
-            self.stats.pipeline.block_s += time.perf_counter() - t0
-        return out
-
-    @staticmethod
-    def _unpack_batch_sync(plan: Plan, values: dict[int, Any],
-                           bctx: _BatchCtx) -> list[list[np.ndarray]]:
         k = bctx.batch
         per_config: list[list[np.ndarray]] = [[] for _ in range(k)]
         for uid in plan.output_ids:
@@ -460,70 +429,72 @@ class LineageRuntime:
         values, lin = self._bind_leaves(plan, leaf_values, leaf_lineage)
         rctx = _RunCtx(depth=costmodel.pipeline_depth())
         self._run_segments(plan, values, lin, bctx=bctx, rctx=rctx)
-        return self._unpack_batch(plan, values, bctx, rctx=rctx)
+        with self.stats.spans("ds.wait"):
+            return self._unpack_batch(plan, values, bctx)
 
     # ------------------------------------------------------------------
     def _bind_leaves(self, plan: Plan,
                      leaf_values: Optional[dict[int, Any]],
                      leaf_lineage: Optional[dict[int, str]]
                      ) -> tuple[dict[int, Any], dict[int, str]]:
-        values: dict[int, Any] = {}
-        lin: dict[int, str] = {}
-        if self.cache is not None:  # lineage only drives reuse probing
-            lin = dict(LEAVES.lineage)
-            if leaf_lineage:
-                lin.update(leaf_lineage)
-        fmts = plan.formats_for(self.sparse_inputs)
-        # chunk-sliced leaves consumed ONLY by the streaming lane stay
-        # host-resident: the streaming executor sparsifies/uploads one
-        # row bucket at a time, so converting the whole leaf up front
-        # would materialize exactly what out-of-core execution avoids.
-        # A non-chunked consumer (materialization fallback) forces the
-        # ordinary device-format bind, and interpreter mode (fuse=False)
-        # executes chunk ops eagerly on whole values so it needs it too.
-        stream_host: set[int] = set()
-        if getattr(plan, "chunk_sliced", None) and self.fuse:
-            non_chunk = {u for ins in plan.instructions
-                         if ins.target != "chunked"
-                         for u in ins.input_ids}
-            stream_host = {u for u in plan.chunk_sliced
-                           if u not in non_chunk}
-        for ins in plan.instructions:
-            for inp in ins.node.inputs:
-                if inp.op == "input" and inp.uid not in values:
-                    src = None
-                    if leaf_values and inp.uid in leaf_values:
-                        src = leaf_values[inp.uid]
-                    elif inp.uid in LEAVES.values:
-                        src = LEAVES.values[inp.uid]
+        with self.stats.spans("ds.bind"):
+            values: dict[int, Any] = {}
+            lin: dict[int, str] = {}
+            if self.cache is not None:  # lineage only drives reuse probing
+                lin = dict(LEAVES.lineage)
+                if leaf_lineage:
+                    lin.update(leaf_lineage)
+            fmts = plan.formats_for(self.sparse_inputs)
+            # chunk-sliced leaves consumed ONLY by the streaming lane stay
+            # host-resident: the streaming executor sparsifies/uploads one
+            # row bucket at a time, so converting the whole leaf up front
+            # would materialize exactly what out-of-core execution avoids.
+            # A non-chunked consumer (materialization fallback) forces the
+            # ordinary device-format bind, and interpreter mode (fuse=False)
+            # executes chunk ops eagerly on whole values so it needs it too.
+            stream_host: set[int] = set()
+            if getattr(plan, "chunk_sliced", None) and self.fuse:
+                non_chunk = {u for ins in plan.instructions
+                             if ins.target != "chunked"
+                             for u in ins.input_ids}
+                stream_host = {u for u in plan.chunk_sliced
+                               if u not in non_chunk}
+            for ins in plan.instructions:
+                for inp in ins.node.inputs:
+                    if inp.op == "input" and inp.uid not in values:
+                        src = None
+                        if leaf_values and inp.uid in leaf_values:
+                            src = leaf_values[inp.uid]
+                        elif inp.uid in LEAVES.values:
+                            src = LEAVES.values[inp.uid]
+                        else:
+                            raise KeyError(
+                                f"unbound input leaf {inp.attr('name')}")
+                        if isinstance(src, FederatedTensor):
+                            # federated leaves bind the metadata object;
+                            # partitions never move unless a `collect`
+                            # instruction says so
+                            values[inp.uid] = src
+                            continue
+                        # sparsify per bind, never memoized: a cached
+                        # conversion cannot detect in-place mutation of the
+                        # source array without a full-content scan that
+                        # costs as much as the conversion itself
+                        arr = np.asarray(src)
+                        if (fmts.get(inp.uid) == backend.BCOO
+                                and inp.uid not in stream_host):
+                            arr = backend.sparsify(arr)
+                        values[inp.uid] = arr
+            for r in plan.roots:  # outputs that are themselves leaves
+                if r.op == "input" and r.uid not in values:
+                    # overrides first, registry fallback: a partial
+                    # leaf_values dict (batched leaves only, see
+                    # evaluate_batch) must not shadow ordinary leaves
+                    if leaf_values and r.uid in leaf_values:
+                        values[r.uid] = leaf_values[r.uid]
                     else:
-                        raise KeyError(
-                            f"unbound input leaf {inp.attr('name')}")
-                    if isinstance(src, FederatedTensor):
-                        # federated leaves bind the metadata object;
-                        # partitions never move unless a `collect`
-                        # instruction says so
-                        values[inp.uid] = src
-                        continue
-                    # sparsify per bind, never memoized: a cached
-                    # conversion cannot detect in-place mutation of the
-                    # source array without a full-content scan that
-                    # costs as much as the conversion itself
-                    arr = np.asarray(src)
-                    if (fmts.get(inp.uid) == backend.BCOO
-                            and inp.uid not in stream_host):
-                        arr = backend.sparsify(arr)
-                    values[inp.uid] = arr
-        for r in plan.roots:  # outputs that are themselves leaves
-            if r.op == "input" and r.uid not in values:
-                # overrides first, registry fallback: a partial
-                # leaf_values dict (batched leaves only, see
-                # evaluate_batch) must not shadow ordinary leaves
-                if leaf_values and r.uid in leaf_values:
-                    values[r.uid] = leaf_values[r.uid]
-                else:
-                    values[r.uid] = LEAVES.values[r.uid]
-        return values, lin
+                        values[r.uid] = LEAVES.values[r.uid]
+            return values, lin
 
     # ------------------------------------------------------------------
     def _run_instructions(self, plan: Plan, values: dict[int, Any],
@@ -539,23 +510,17 @@ class LineageRuntime:
             node = ins.node
             lhash = None
             if self.cache is not None and ins.probe:
-                lhash = _lhash_rec(node, lin, lmemo)
-                hit = self.cache.probe(lhash)
+                with self.stats.spans("ds.probe"):
+                    lhash = _lhash_rec(node, lin, lmemo)
+                    hit = self.cache.probe(lhash)
                 if hit is not None:
                     values[ins.out_id] = _coerce_format(
                         hit, fmts.get(ins.out_id, backend.DENSE))
                     self.stats.reused += 1
                     self._free(values, ins.last_use_of)
                     continue
-            t0, tt0 = time.perf_counter(), self.stats.trace_time
             out = self._exec_one(ins, values, fmts)
-            # per-site sub-segment compiles (federated ops) book into
-            # trace_time inside LocalSite.execute — keep them out of
-            # exec_time, mirroring _execute_cached's split
-            dt = (time.perf_counter() - t0
-                  - (self.stats.trace_time - tt0))
             self.stats.executed += 1
-            self.stats.exec_time += dt
             values[ins.out_id] = out
             if lhash is not None:
                 # admission was decided by the compile-time gate; store
@@ -652,8 +617,9 @@ class LineageRuntime:
                     seg_key += "|unshard"  # local-equivalent fallback
             lhash = None
             if reuse and last.probe:
-                lhash = _lhash_rec(last.node, lin, lmemo)
-                hit = self.cache.probe(lhash)
+                with self.stats.spans("ds.probe"):
+                    lhash = _lhash_rec(last.node, lin, lmemo)
+                    hit = self.cache.probe(lhash)
                 if hit is not None:
                     values[last.out_id] = _coerce_format(
                         hit, fmts.get(last.out_id, backend.DENSE))
@@ -679,13 +645,8 @@ class LineageRuntime:
                 # boundaries dispatch per-site compiled sub-segments
                 # and meter the exchange; other host ops (quantile) run
                 # their kernel eagerly, outside any jit trace
-                t0, tt0 = time.perf_counter(), self.stats.trace_time
                 out = self._exec_one(last, values, fmts,
                                      bctx=bctx if batched else None)
-                # per-site compiles booked into trace_time by
-                # LocalSite.execute; exec_time gets the rest
-                self.stats.exec_time += (time.perf_counter() - t0
-                                         - (self.stats.trace_time - tt0))
                 outs = (out,)
                 self.stats.executed += 1
             else:
@@ -822,42 +783,39 @@ class LineageRuntime:
     # ------------------------------------------------------------------
     def _execute_cached(self, seg_key: str, build_fn, args, jcache,
                         rctx: Optional[_RunCtx] = None,
-                        donate: tuple = ()):
+                        donate: tuple = (), span: str = "ds.dispatch"):
         """Run one executable through the jit cache (lookup, compile on
-        miss, execute), accounting trace/exec time.
+        miss, execute); the call is booked under `span`.
 
         Pipeline depth 1 (or no `rctx`): block until every output is
-        ready — the pre-pipeline behaviour, bitwise and meter
-        identical. Depth >= 2: return the outputs as in-flight device
-        arrays (XLA dispatches asynchronously); the sync happens at
-        plan roots / probe materialization / host-op boundaries, and
-        the dispatch cost is metered into `stats.pipeline`."""
+        ready — the pre-pipeline behaviour. Depth >= 2: return the
+        outputs as in-flight device arrays (XLA dispatches
+        asynchronously); the sync happens at plan roots / probe
+        materialization / host-op boundaries."""
+        spans = self.stats.spans
         key, exe = jcache.lookup(seg_key, args)
         if exe is None:
             try:
-                exe, dt_trace = jcache.compile(key, build_fn(), args,
-                                               donate_argnums=donate)
+                with spans("ds.jit.compile"):
+                    exe = jcache.compile(key, build_fn(), args,
+                                         donate_argnums=donate)
             except faults.InjectedFault as e:
                 # typed so the segment loop can take its degradation
                 # ladder (interpreter fallback); a genuine compile error
                 # propagates, so a failing device compile is never
                 # hidden behind the interpreter
                 raise CompileFailedError(seg_key, e) from e
-            self.stats.trace_time += dt_trace
+            self.stats.compiles += 1
         else:
             self.stats.jit_cache_hits += 1
-        t0 = time.perf_counter()
-        outs = exe(*args)
+        with spans(span):
+            outs = exe(*args)
         if rctx is None or rctx.depth < 2:
-            for o in outs:
-                backend.block_ready(o)
-            self.stats.exec_time += time.perf_counter() - t0
+            with spans("ds.wait"):
+                for o in outs:
+                    backend.block_ready(o)
         else:
-            dt = time.perf_counter() - t0
-            self.stats.exec_time += dt
-            plog = self.stats.pipeline
-            plog.async_segments += 1
-            plog.dispatch_s += dt
+            self.stats.pipeline.async_segments += 1
         return outs
 
     # ------------------------------------------------------------------
@@ -955,6 +913,7 @@ class LineageRuntime:
         """
         reuse = self.cache is not None
         log = self.stats.streaming
+        spans = self.stats.spans
         out_set = set(seg.output_uids)
         out_ins = {ins.out_id: ins for ins in seg.instructions
                    if ins.out_id in out_set}
@@ -963,14 +922,15 @@ class LineageRuntime:
         lhashes: dict[int, str] = {}
         hits: dict[int, Any] = {}
         if reuse:
-            for uid in seg.output_uids:
-                if not out_ins[uid].probe:
-                    continue
-                lh = _lhash_rec(out_ins[uid].node, lin, lmemo)
-                lhashes[uid] = lh
-                got = self.cache.probe(lh)
-                if got is not None:
-                    hits[uid] = got
+            with spans("ds.probe"):
+                for uid in seg.output_uids:
+                    if not out_ins[uid].probe:
+                        continue
+                    lh = _lhash_rec(out_ins[uid].node, lin, lmemo)
+                    lhashes[uid] = lh
+                    got = self.cache.probe(lh)
+                    if got is not None:
+                        hits[uid] = got
         # short-circuit iff every output is either a cache-hit partial
         # aggregate or a chunk-invariant generator (a target-neutral
         # literal that rode along) — escaping chunked-placement values
@@ -1003,14 +963,15 @@ class LineageRuntime:
 
         log.chunked_segments += 1
         host: dict[int, np.ndarray] = {}
-        for u in sliced:
-            a = values[u]
-            if backend.is_sparse(a):
-                # materialization fallback for a sparse interior value
-                # entering the stream row-aligned (leaves are kept
-                # host-dense by _bind_leaves; this is the rare rest)
-                a = a.todense()
-            host[u] = np.asarray(a)
+        with spans("ds.wait"):  # free for host leaves; syncs interiors
+            for u in sliced:
+                a = values[u]
+                if backend.is_sparse(a):
+                    # materialization fallback for a sparse interior
+                    # value entering the stream row-aligned (leaves are
+                    # kept host-dense by _bind_leaves; the rare rest)
+                    a = a.todense()
+                host[u] = np.asarray(a)
         rows = host[sliced[0]].shape[0]
         for u in sliced[1:]:
             if host[u].shape[0] != rows:
@@ -1035,9 +996,11 @@ class LineageRuntime:
         # every chunk's identity (a changed mean shifts every bucket)
         rep_fp = ""
         if reuse:
-            rep_fp = "|".join(
-                _fingerprint(np.asarray(backend.densify(values[u])))
-                for u in seg.input_uids if u not in host)
+            with spans("ds.wait"):
+                reps = [np.asarray(backend.densify(values[u]))
+                        for u in seg.input_uids if u not in host]
+            with spans("ds.stream.fingerprint"):
+                rep_fp = "|".join(_fingerprint(a) for a in reps)
         cost_each = (sum(i.est_cost_s for i in out_ins.values())
                      / n_chunks)
         builder = self._seg_builder(seg, fmts, None)
@@ -1059,21 +1022,22 @@ class LineageRuntime:
         accs: dict[int, Any] = {u: None for u in seg.output_uids}
 
         def _accumulate(parts, live: int) -> None:
-            for uid, p in zip(seg.output_uids, parts, strict=True):
-                prev = accs[uid]
-                mode = modes[uid]
-                if mode == "concat":
-                    accs[uid] = [p] if prev is None else prev + [p]
-                elif prev is None:
-                    accs[uid] = p
-                elif mode == "sum":
-                    accs[uid] = prev + p
-                    log.combines += 1
-                # "keep": chunk-invariant — the first value stands
-            acc_bytes = sum(_reuse_nbytes(v) for v in accs.values()
-                            if v is not None)
-            log.peak_live_bytes = max(log.peak_live_bytes,
-                                      live + acc_bytes)
+            with spans("ds.stream.combine"):
+                for uid, p in zip(seg.output_uids, parts, strict=True):
+                    prev = accs[uid]
+                    mode = modes[uid]
+                    if mode == "concat":
+                        accs[uid] = [p] if prev is None else prev + [p]
+                    elif prev is None:
+                        accs[uid] = p
+                    elif mode == "sum":
+                        accs[uid] = prev + p
+                        log.combines += 1
+                    # "keep": chunk-invariant — the first value stands
+                acc_bytes = sum(_reuse_nbytes(v) for v in accs.values()
+                                if v is not None)
+                log.peak_live_bytes = max(log.peak_live_bytes,
+                                          live + acc_bytes)
 
         pdepth = 1
         if rctx is not None and rctx.depth >= 2:
@@ -1085,12 +1049,14 @@ class LineageRuntime:
                 e = min(s + c, rows)
                 parts, ckey, live = None, None, 0
                 if reuse:
-                    fps = ",".join(_fingerprint(host[u][s:e])
-                                   for u in sliced)
-                    ckey = hashlib.sha1(
-                        f"chunkpart|{seg_key}|{s}:{e}|{rep_fp}|{fps}"
-                        .encode()).hexdigest()
-                    parts = self.cache.probe(ckey)
+                    with spans("ds.stream.fingerprint"):
+                        fps = ",".join(_fingerprint(host[u][s:e])
+                                       for u in sliced)
+                    with spans("ds.probe"):
+                        ckey = hashlib.sha1(
+                            f"chunkpart|{seg_key}|{s}:{e}|{rep_fp}|{fps}"
+                            .encode()).hexdigest()
+                        parts = self.cache.probe(ckey)
                     if parts is not None:
                         log.chunks_reused += 1
                 if parts is None:
@@ -1105,15 +1071,17 @@ class LineageRuntime:
                         else:
                             args.append(values[u])
                     outs = self._execute_cached(seg_key, builder, args,
-                                                jcache)
+                                                jcache,
+                                                span="ds.stream.dispatch")
                     # partials densify to HOST arrays: their only
                     # consumer is the `combine` densify boundary, numpy
                     # accumulators add chunk-by-chunk regardless of the
                     # slice's format, and host adds skip the per-op
                     # device dispatch that would otherwise dominate
                     # warm (all-chunks-reused) runs
-                    parts = tuple(np.asarray(backend.densify(o))
-                                  for o in outs)
+                    with spans("ds.wait"):
+                        parts = tuple(np.asarray(backend.densify(o))
+                                      for o in outs)
                     log.chunks += 1
                     log.bytes_streamed += live
                     if ckey is not None:
@@ -1125,9 +1093,10 @@ class LineageRuntime:
                 seg, seg_key, fmts, values, jcache, host, sliced,
                 rows, c, reuse, rep_fp, cost_each, builder,
                 _accumulate, row_bytes, rctx)
-        for uid, m in modes.items():
-            if m == "concat" and accs[uid] is not None:
-                accs[uid] = np.concatenate(accs[uid], axis=0)
+        with spans("ds.stream.combine"):
+            for uid, m in modes.items():
+                if m == "concat" and accs[uid] is not None:
+                    accs[uid] = np.concatenate(accs[uid], axis=0)
         # cached full aggregates win (identical values, mirrors the
         # interpreter's per-instruction hits); streamed accumulators
         # fill the rest and populate the cache
@@ -1181,6 +1150,7 @@ class LineageRuntime:
         leaves a hung thread or a silently-dropped bucket."""
         log = self.stats.streaming
         plog = self.stats.pipeline
+        spans = self.stats.spans
         # block-sum tables are only valid when the bound value IS the
         # registered leaf buffer (np.asarray of the registry array is
         # identity); an override/densified copy falls back to hashing
@@ -1210,7 +1180,6 @@ class LineageRuntime:
                 # (probe_faults=False) re-preps are injection-free so
                 # recovery always completes.
                 faults.io_entry("chunk_prefetch")
-            t0 = time.perf_counter()
             args, live = [], 0
             for u in seg.input_uids:
                 if u in host:
@@ -1221,41 +1190,47 @@ class LineageRuntime:
                     args.append(a)
                 else:
                     args.append(values[u])
-            return args, live, time.perf_counter() - t0
+            return args, live
 
-        spans = [(s, min(s + c, rows)) for s in range(0, rows, c)]
-        pdepth = costmodel.prefetch_depth(row_bytes, len(spans))
+        def _prep_on_worker(s: int, e: int):
+            with spans("ds.stream.prep"):
+                return _prep(s, e)
+
+        bounds = [(s, min(s + c, rows)) for s in range(0, rows, c)]
+        pdepth = costmodel.prefetch_depth(row_bytes, len(bounds))
         ex = ThreadPoolExecutor(max_workers=1,
                                 thread_name_prefix="chunk-prefetch")
         inflight: deque = deque()  # (s, e, ckey, parts, fut)
         nxt = 0
         try:
-            while inflight or nxt < len(spans):
+            while inflight or nxt < len(bounds):
                 # keep pdepth buckets resolved/in-flight ahead of the
                 # consumer; fingerprint+probe on the main thread, args
                 # prep for misses on the worker
-                while len(inflight) < pdepth and nxt < len(spans):
-                    s, e = spans[nxt]
+                while len(inflight) < pdepth and nxt < len(bounds):
+                    s, e = bounds[nxt]
                     nxt += 1
                     ckey, parts, fut = None, None, None
                     if reuse:
-                        fps = ",".join(_bucket_fp(u, s, e)
-                                       for u in sliced)
-                        ckey = hashlib.sha1(
-                            f"chunkpart|{seg_key}|{s}:{e}|{rep_fp}|{fps}"
-                            .encode()).hexdigest()
-                        parts = self.cache.probe(ckey)
+                        with spans("ds.stream.fingerprint"):
+                            fps = ",".join(_bucket_fp(u, s, e)
+                                           for u in sliced)
+                        with spans("ds.probe"):
+                            ckey = hashlib.sha1(
+                                f"chunkpart|{seg_key}|{s}:{e}|{rep_fp}|"
+                                f"{fps}".encode()).hexdigest()
+                            parts = self.cache.probe(ckey)
                         if parts is not None:
                             log.chunks_reused += 1
                     if parts is None:
-                        fut = ex.submit(_prep, s, e)
+                        fut = ex.submit(_prep_on_worker, s, e)
                         plog.prefetch_issued += 1
                     inflight.append((s, e, ckey, parts, fut))
                 s, e, ckey, parts, fut = inflight.popleft()
                 live = 0
                 if parts is None:
                     try:
-                        args, live, dt_prep = fut.result()
+                        args, live = fut.result()
                     except faults.InjectedFault:
                         # degradation ladder: the prefetch worker died
                         # of an injected fault mid-stream — reclaim this
@@ -1274,31 +1249,35 @@ class LineageRuntime:
                                 plog.prefetch_cancelled += 1
                             tail.append((s2, e2, ck2, p2))
                         tail.extend((s3, e3, None, None)
-                                    for s3, e3 in spans[nxt:])
-                        nxt = len(spans)
+                                    for s3, e3 in bounds[nxt:])
+                        nxt = len(bounds)
                         for s2, e2, ck2, p2 in tail:
                             live2 = 0
                             if p2 is None and reuse and ck2 is None:
-                                # spans the pipeline never resolved:
+                                # buckets the pipeline never resolved:
                                 # probe the chunk cache like the sync
                                 # loop would (same keys, same hits)
-                                fps = ",".join(_bucket_fp(u, s2, e2)
-                                               for u in sliced)
-                                ck2 = hashlib.sha1(
-                                    f"chunkpart|{seg_key}|{s2}:{e2}|"
-                                    f"{rep_fp}|{fps}"
-                                    .encode()).hexdigest()
-                                p2 = self.cache.probe(ck2)
+                                with spans("ds.stream.fingerprint"):
+                                    fps = ",".join(_bucket_fp(u, s2, e2)
+                                                   for u in sliced)
+                                with spans("ds.probe"):
+                                    ck2 = hashlib.sha1(
+                                        f"chunkpart|{seg_key}|{s2}:{e2}|"
+                                        f"{rep_fp}|{fps}"
+                                        .encode()).hexdigest()
+                                    p2 = self.cache.probe(ck2)
                                 if p2 is not None:
                                     log.chunks_reused += 1
                             if p2 is None:
-                                args2, live2, _ = _prep(
+                                args2, live2 = _prep(
                                     s2, e2, probe_faults=False)
                                 outs2 = self._execute_cached(
-                                    seg_key, builder, args2, jcache)
-                                p2 = tuple(
-                                    np.asarray(backend.densify(o))
-                                    for o in outs2)
+                                    seg_key, builder, args2, jcache,
+                                    span="ds.stream.dispatch")
+                                with spans("ds.wait"):
+                                    p2 = tuple(
+                                        np.asarray(backend.densify(o))
+                                        for o in outs2)
                                 log.chunks += 1
                                 log.bytes_streamed += live2
                                 if ck2 is not None:
@@ -1307,13 +1286,12 @@ class LineageRuntime:
                             accumulate(p2, live2)
                         return
                     plog.prefetch_hits += 1
-                    plog.prefetch_s += dt_prep
                     outs = self._execute_cached(seg_key, builder, args,
-                                                jcache, rctx=rctx)
-                    t0 = time.perf_counter()
-                    parts = tuple(np.asarray(backend.densify(o))
-                                  for o in outs)
-                    plog.block_s += time.perf_counter() - t0
+                                                jcache, rctx=rctx,
+                                                span="ds.stream.dispatch")
+                    with spans("ds.wait"):
+                        parts = tuple(np.asarray(backend.densify(o))
+                                      for o in outs)
                     log.chunks += 1
                     log.bytes_streamed += live
                     if ckey is not None:
@@ -1360,6 +1338,7 @@ class LineageRuntime:
             # their local-equivalent base kernels (no collectives)
             unshard=(node.op in backend.SHARD_EXEC_OPS
                      or node.placement == "sharded"))
+        spans = self.stats.spans
         if bctx is not None:
             import jax.numpy as jnp
             bpos = {i for i, u in enumerate(ins.input_ids)
@@ -1368,14 +1347,16 @@ class LineageRuntime:
             # host ops loop over the TRUE k, not the padded bucket —
             # padding configs duplicate the last one, so their result
             # is re-padded in, never recomputed
-            rows = [kern(*[a[j] if i in bpos else a
-                           for i, a in enumerate(args)])
-                    for j in range(bctx.batch)]
-            out = _pad_axis0(jnp.stack(rows, axis=0), bctx.bucket)
+            with spans("ds.dispatch"):
+                rows = [kern(*[a[j] if i in bpos else a
+                               for i, a in enumerate(args)])
+                        for j in range(bctx.batch)]
+                out = _pad_axis0(jnp.stack(rows, axis=0), bctx.bucket)
+        else:
+            with spans("ds.dispatch"):
+                out = kern(*[values[u] for u in ins.input_ids])
+        with spans("ds.wait"):
             backend.block_ready(out)
-            return out
-        out = kern(*[values[u] for u in ins.input_ids])
-        backend.block_ready(out)
         return out
 
     # ------------------------------------------------------------------

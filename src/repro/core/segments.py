@@ -63,6 +63,7 @@ from typing import TYPE_CHECKING, Optional
 
 from . import backend
 from .dag import Node, structural_key
+from .tracing import segment_fn_name
 
 if TYPE_CHECKING:  # avoid circular import; Plan imports this lazily
     from .compiler import Instruction, Plan
@@ -325,6 +326,11 @@ def build_segment_fn(seg: Segment, formats: Optional[dict] = None,
                                  for u in input_ids])
         return tuple(env[u] for u in out_uids)
 
+    # jit names the XLA module after the closure (`jit_ds_add_solve`),
+    # and vmap/shard_map keep the name: the device trace then says
+    # which segment ran. The jit-cache key does not read it.
+    run.__name__ = run.__qualname__ = segment_fn_name(
+        ins.node.op for ins in instructions)
     return run
 
 

@@ -59,32 +59,36 @@ def parfor(configs: Sequence, build_fn: Callable,
         raise ValueError(
             f"parfor mode {mode!r} not in auto|vmap|shard|sequential")
     rt = runtime or get_runtime()
+    spans = rt.stats.spans
     config_outputs: list[list[LTensor]] = []
-    for cfg in configs:
-        out = build_fn(cfg)
-        config_outputs.append([out] if isinstance(out, LTensor)
-                              else list(out))
+    with spans("ds.parfor.build"):
+        for cfg in configs:
+            out = build_fn(cfg)
+            config_outputs.append([out] if isinstance(out, LTensor)
+                                  else list(out))
     k = len(config_outputs)
     if k == 0:
         return []
     if mode in ("vmap", "shard") and k < 2:
         raise BatchingError("batching needs >= 2 configurations")
     if mode != "sequential" and k >= 2:
-        try:
-            bplan = compile_batched(
-                config_outputs, reuse_enabled=rt.cache is not None,
-                opt_level=rt.opt_level)
-        except BatchingError:
-            if mode in ("vmap", "shard"):
-                raise
-            bplan = None
+        with spans("ds.parfor.plan"):
+            try:
+                bplan = compile_batched(
+                    config_outputs, reuse_enabled=rt.cache is not None,
+                    opt_level=rt.opt_level)
+            except BatchingError:
+                if mode in ("vmap", "shard"):
+                    raise
+                bplan = None
+            if bplan is not None:
+                roots_list = [[o.node for o in outs]
+                              for outs in config_outputs]
+                bplan.mode = (mode if mode in ("vmap", "shard")
+                              else choose_mode(
+                    bplan, roots_list, rt.cache is not None,
+                    rt.sparse_inputs))
         if bplan is not None:
-            roots_list = [[o.node for o in outs]
-                          for outs in config_outputs]
-            bplan.mode = (mode if mode in ("vmap", "shard")
-                          else choose_mode(
-                bplan, roots_list, rt.cache is not None,
-                rt.sparse_inputs))
             try:
                 if bplan.mode in ("vmap", "shard"):
                     return rt.evaluate_batch(bplan)
@@ -93,7 +97,11 @@ def parfor(configs: Sequence, build_fn: Callable,
                 # unbind them so repeated calls don't grow the global
                 # leaf registry without bound
                 bplan.release_leaves()
-    return [rt.evaluate(outs) for outs in config_outputs]
+    results = []
+    for outs in config_outputs:
+        with spans("ds.parfor.config"):
+            results.append(rt.evaluate(outs))
+    return results
 
 
 def grid_search_lm(X: LTensor, y: LTensor, lambdas: Sequence[float],

@@ -527,36 +527,39 @@ class ModelServer:
             return
         jcache = get_jit_cache()
         log = self.runtime.stats.serving
-        t0 = time.monotonic()
-        try:
-            if stacked is None:
-                stacked = [np.stack([r.arrays[i] for r in batch])
-                           for i in range(len(self.script._arg_shapes))]
-            miss0 = jcache.stats.misses
-            results = self.runtime.replay_batch(self._bplan, stacked, k)
-            # the hot-path hygiene counter: any compile after deploy
-            # warmup is a retrace the bucket warming should have covered
-            log.retraces += jcache.stats.misses - miss0
-            log.requests += k
-            log.batches += 1
-            log.max_coalesce = max(log.max_coalesce, k)
-            log.padded += bucket_size(k) - k
-            log.queue_wait_s += sum(t0 - r.t_enqueue for r in batch)
-            for req, res in zip(batch, results):
-                req._result = res
-                req.done.set()
-        except BaseException as e:  # deliver, don't kill the dispatcher
-            for req in batch:
-                if not req.done.is_set():
-                    req.error = e
+        with self.runtime.stats.spans("ds.serve.batch"):
+            t0 = time.monotonic()
+            try:
+                if stacked is None:
+                    n_args = len(self.script._arg_shapes)
+                    stacked = [np.stack([r.arrays[i] for r in batch])
+                               for i in range(n_args)]
+                miss0 = jcache.stats.misses
+                results = self.runtime.replay_batch(self._bplan, stacked,
+                                                    k)
+                # the hot-path hygiene counter: any compile after
+                # deploy warmup is a retrace the bucket warming should
+                # have covered
+                log.retraces += jcache.stats.misses - miss0
+                log.requests += k
+                log.batches += 1
+                log.max_coalesce = max(log.max_coalesce, k)
+                log.padded += bucket_size(k) - k
+                log.queue_wait_s += sum(t0 - r.t_enqueue for r in batch)
+                for req, res in zip(batch, results):
+                    req._result = res
                     req.done.set()
-        finally:
-            dt = time.monotonic() - t0
-            log.busy_s += dt
-            # per-dispatch latency through the rescued straggler
-            # monitor (repro.distributed.fault.StepMonitor): p50/p99
-            # and median+k·MAD flags surface in stats['faults']
-            self.runtime.stats.faults.record_dispatch(log.batches, dt)
+            except BaseException as e:  # deliver, don't kill dispatch
+                for req in batch:
+                    if not req.done.is_set():
+                        req.error = e
+                        req.done.set()
+            finally:
+                dt = time.monotonic() - t0
+                # per-dispatch latency through the rescued straggler
+                # monitor (repro.distributed.fault.StepMonitor): p50/p99
+                # and median+k·MAD flags surface in stats['faults']
+                self.runtime.stats.faults.record_dispatch(log.batches, dt)
 
     # -- introspection -------------------------------------------------
     def explain(self) -> str:

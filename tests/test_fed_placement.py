@@ -243,17 +243,17 @@ class TestFederatedReuse:
         rt = LineageRuntime()      # cache is process-global by design
         plan = compile_plan([_lmds_graph(X, Y)])
         rt.run_plan(plan)          # trace + compile per-site segments
-        assert rt.stats.trace_time > 0  # per-site compiles booked here
+        assert rt.stats.compiles > 0  # per-site compiles booked here
         st = get_jit_cache().stats
         before = st.hits
         hits_before = rt.stats.jit_cache_hits
-        trace_before = rt.stats.trace_time
+        compiles_before = rt.stats.compiles
         rt.run_plan(plan)          # warm replay
         # >= 6 warm per-site lookups (gram + xtv on 3 sites) plus the
         # fused local segments
         assert st.hits - before >= 6
         assert rt.stats.jit_cache_hits - hits_before >= 6
-        assert rt.stats.trace_time == trace_before  # nothing re-traced
+        assert rt.stats.compiles == compiles_before  # nothing re-traced
 
 
 class TestValidation:

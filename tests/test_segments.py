@@ -234,12 +234,12 @@ class TestJitExecutableCache:
         r = np.random.default_rng(1)
         ps(r.normal(size=(80, 5)), r.normal(size=(80, 1)))
         assert rt.stats.segments >= 1
-        assert rt.stats.trace_time > 0.0  # first call traced
+        assert rt.stats.compiles > 0  # first call traced
         hits_before = rt.stats.jit_cache_hits
-        trace_before = rt.stats.trace_time
+        compiles_before = rt.stats.compiles
         ps(r.normal(size=(80, 5)), r.normal(size=(80, 1)))
         assert rt.stats.jit_cache_hits > hits_before  # warm executables
-        assert rt.stats.trace_time == trace_before   # no re-trace
+        assert rt.stats.compiles == compiles_before   # no re-trace
 
     def test_structurally_identical_scripts_share_executables(self, rng):
         clear_jit_cache()
@@ -252,7 +252,7 @@ class TestJitExecutableCache:
         PreparedScript(fn, [(48, 4), (48, 1)], runtime=rt2)(
             rng.normal(size=(48, 4)), rng.normal(size=(48, 1)))
         # second script re-traced nothing: same structural keys + shapes
-        assert rt2.stats.trace_time == 0.0
+        assert rt2.stats.compiles == 0
         assert rt2.stats.jit_cache_hits >= rt2.stats.segments
 
     def test_stats_accounting(self, rng):
