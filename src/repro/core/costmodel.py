@@ -62,6 +62,12 @@ FED_TRIP_S = 50e-6    # per-site round-trip launch latency
 # boundary.
 PROBE_MIN_COST_S = MIN_CACHE_COST_S
 
+# Host-to-device copy rate of one whole float64 array: 800 MB in 98-141
+# ms on a TPU v5e (PERF.md section 5). Prices a leaf's device copy in
+# the reuse cache (`ReuseCache.leaf_upload`): its eviction value is the
+# copy it saves.
+H2D_BW = 8.1e9        # bytes/s
+
 
 def _numel(shape) -> int:
     n = 1

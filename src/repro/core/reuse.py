@@ -8,12 +8,20 @@ operators (gram/xtv over rbind/cbind) so their pieces become cache hits.
 
 Eviction follows SystemDS's cost-and-size heuristic: keep entries with
 high (compute-cost / byte), weighted by recency (LRU decay).
+
+The same lineage contract covers the device copy of a leaf: a leaf is
+immutable from `input_tensor` on, so its copy on the device is reusable
+under the leaf's lineage id for as long as its intermediates are
+(`leaf_upload`). Copies live beside the entries, under the same byte
+budget, with their own counters; they take only budget no entry holds
+and are evicted before any entry, so every entry's admission, eviction
+and hit is what it would be without them.
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import numpy as np
 
@@ -81,11 +89,20 @@ class ReuseStats:
     evictions: int = 0
     bytes_cached: int = 0
     time_saved: float = 0.0   # Σ cost of hit entries
+    # device copies of leaves (`ReuseCache.leaf_upload`), apart from
+    # the entries' counts above
+    uploads: int = 0           # misses that copied a leaf to the device
+    upload_hits: int = 0       # calls served by a resident copy
+    upload_bytes: int = 0      # resident bytes of the copies
+    upload_evictions: int = 0
 
     def as_dict(self) -> dict:
         return dict(probes=self.probes, hits=self.hits, misses=self.misses,
                     evictions=self.evictions, bytes=self.bytes_cached,
-                    time_saved_s=round(self.time_saved, 6))
+                    time_saved_s=round(self.time_saved, 6),
+                    uploads=self.uploads, upload_hits=self.upload_hits,
+                    upload_bytes=self.upload_bytes,
+                    upload_evictions=self.upload_evictions)
 
 
 class ReuseCache:
@@ -97,6 +114,7 @@ class ReuseCache:
         self.budget = int(budget_bytes)
         self.policy = policy
         self.entries: dict[str, CacheEntry] = {}
+        self.uploads: dict[tuple, CacheEntry] = {}  # leaf device copies
         self.stats = ReuseStats()
 
     # -- interface ----------------------------------------------------------
@@ -130,9 +148,36 @@ class ReuseCache:
                                          last_used=time.perf_counter())
         self.stats.bytes_cached += size
 
+    def leaf_upload(self, key: tuple, host: np.ndarray,
+                    copy: Callable[[np.ndarray], Any], cost: float) -> Any:
+        """The device copy of the leaf `key` names (its lineage id, dtype
+        and shape), made by `copy(host)` on a miss; `cost` is the copy's
+        estimated seconds, its eviction value. A copy is admitted only
+        into budget no entry holds, displacing older copies but never
+        an entry; a leaf that does not fit is returned as `host`, for
+        the executable to copy per call as it would without a cache."""
+        e = self.uploads.get(key)
+        if e is not None:
+            e.hits += 1
+            e.last_used = time.perf_counter()
+            self.stats.upload_hits += 1
+            return e.value
+        size = nbytes(host)
+        if self.stats.bytes_cached + size > self.budget:
+            return host
+        self._evict(self.uploads, size)
+        value = copy(host)
+        self.uploads[key] = CacheEntry(value=value, size=size, cost=cost,
+                                       last_used=time.perf_counter())
+        self.stats.uploads += 1
+        self.stats.upload_bytes += size
+        return value
+
     def clear(self) -> None:
         self.entries.clear()
+        self.uploads.clear()
         self.stats.bytes_cached = 0
+        self.stats.upload_bytes = 0
 
     # -- eviction -------------------------------------------------------------
     def _score(self, e: CacheEntry, now: float) -> float:
@@ -143,14 +188,24 @@ class ReuseCache:
         return (e.cost / max(e.size, 1)) / (1.0 + 0.01 * age)
 
     def _make_room(self, need: int) -> None:
-        if self.stats.bytes_cached + need <= self.budget:
+        # leaf copies go first: an entry is evicted only once no copy
+        # holds budget, exactly when it would be without copies
+        self._evict(self.uploads, need)
+        self._evict(self.entries, need)
+
+    def _evict(self, pool: dict, need: int) -> None:
+        st = self.stats
+        if st.bytes_cached + st.upload_bytes + need <= self.budget:
             return
         now = time.perf_counter()
-        victims = sorted(self.entries.items(),
-                         key=lambda kv: self._score(kv[1], now))
+        victims = sorted(pool.items(), key=lambda kv: self._score(kv[1], now))
         for key, e in victims:
-            if self.stats.bytes_cached + need <= self.budget:
+            if st.bytes_cached + st.upload_bytes + need <= self.budget:
                 break
-            del self.entries[key]
-            self.stats.bytes_cached -= e.size
-            self.stats.evictions += 1
+            del pool[key]
+            if pool is self.uploads:
+                st.upload_bytes -= e.size
+                st.upload_evictions += 1
+            else:
+                st.bytes_cached -= e.size
+                st.evictions += 1

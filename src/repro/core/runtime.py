@@ -250,10 +250,13 @@ class _RunCtx:
     (`put`). Leaf values, cache hits, host-path and chunked outputs are
     never admitted. Kept per-run (not on the runtime) so concurrent
     `run_plan` calls on one runtime cannot alias each other's
-    ownership."""
+    ownership. `leaves` maps each dense registry leaf this run bound
+    as a host array to the key of its device copy in the reuse cache
+    (`LineageRuntime._upload_keys`); empty without a cache."""
 
     depth: int = 1
     owned: set = field(default_factory=set)
+    leaves: dict = field(default_factory=dict)
 
 
 @dataclass
@@ -324,7 +327,9 @@ class LineageRuntime:
         if self.fuse:
             self._run_segments(
                 plan, values, lin,
-                rctx=_RunCtx(depth=costmodel.pipeline_depth()))
+                rctx=_RunCtx(depth=costmodel.pipeline_depth(),
+                             leaves=self._upload_keys(values,
+                                                      leaf_values)))
         else:
             self._run_instructions(plan, values, lin)
         # plan roots are THE sync point of the async pipeline (depth
@@ -365,7 +370,8 @@ class LineageRuntime:
             uid: pad_batch(np.asarray(LEAVES.values[uid]), bplan.bucket)
             for uid in bplan.batched_leaf_uids}
         values, lin = self._bind_leaves(plan, leaf_values, None)
-        rctx = _RunCtx(depth=costmodel.pipeline_depth())
+        rctx = _RunCtx(depth=costmodel.pipeline_depth(),
+                       leaves=self._upload_keys(values, leaf_values))
         self._run_segments(plan, values, lin, bctx=bctx, rctx=rctx)
         with self.stats.spans("ds.wait"):
             return self._unpack_batch(plan, values, bctx)
@@ -497,6 +503,45 @@ class LineageRuntime:
             return values, lin
 
     # ------------------------------------------------------------------
+    def _upload_keys(self, values: dict[int, Any],
+                     overrides: Optional[dict[int, Any]]
+                     ) -> dict[int, tuple]:
+        """The dense leaves bound from the registry as host arrays, each
+        with the key of its device copy in the reuse cache: the leaf's
+        lineage id, dtype and shape. A run's own arrays (a prepared
+        script's arguments, a batch's stacks) are not registry leaves
+        and are left out."""
+        if self.cache is None:
+            return {}
+        return {u: (LEAVES.lineage[u], a.dtype.str, a.shape)
+                for u, a in values.items()
+                if isinstance(a, np.ndarray) and LEAVES.values.get(u) is a
+                and not (overrides and u in overrides)}
+
+    def _device_args(self, seg, args: list, rctx: _RunCtx) -> list:
+        """`args` with each registry leaf replaced by its device copy
+        from the reuse cache, copied once under its lineage instead of
+        on every call. `values` keeps the host array, which the
+        streaming lane slices."""
+        if not rctx.leaves:
+            return args
+        out = list(args)
+        for i, u in enumerate(seg.input_uids):
+            key = rctx.leaves.get(u)
+            if key is not None:
+                out[i] = self.cache.leaf_upload(
+                    key, out[i], self._upload,
+                    out[i].nbytes / costmodel.H2D_BW)
+        return out
+
+    def _upload(self, host: np.ndarray):
+        import jax
+        with self.stats.spans("ds.upload"):
+            dev = jax.device_put(host)
+            dev.block_until_ready()
+        return dev
+
+    # ------------------------------------------------------------------
     def _run_instructions(self, plan: Plan, values: dict[int, Any],
                           lin: dict[int, str]) -> None:
         """Per-instruction interpreter (the `fuse=False` fallback);
@@ -605,6 +650,8 @@ class LineageRuntime:
                                f"{mesh_spec.key_tag()}")
                     self.stats.shard.config_sharded_segments += 1
             seg_sharded = getattr(seg, "sharded", False)
+            # shard_map places its own operands
+            local = not seg_sharded and not (batched and bctx.cshard > 1)
             if seg_sharded:
                 if jmesh is not None:
                     from .jit_cache import mesh_key_tag
@@ -632,7 +679,9 @@ class LineageRuntime:
                         # and everything only it needed — mirroring what
                         # the interpreter computes after the same hit
                         self._run_compensation(
-                            seg, seg_key, fmts, args, rest, last.out_id,
+                            seg, seg_key, fmts,
+                            self._device_args(seg, args, rctx)
+                            if local else args, rest, last.out_id,
                             jcache, values,
                             bctx=bctx if batched else None,
                             jmesh=jmesh, rctx=rctx)
@@ -664,6 +713,8 @@ class LineageRuntime:
                     plog.donated_buffers += len(don)
                     plog.donated_bytes += sum(
                         _reuse_nbytes(args[i]) for i in don)
+                if local:
+                    args = self._device_args(seg, args, rctx)
                 try:
                     outs = self._execute_cached(
                         seg_key, self._seg_builder(seg, fmts,

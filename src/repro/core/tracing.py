@@ -19,6 +19,8 @@ they cover. The names are fixed (no per-segment strings):
 ``ds.probe``                lineage hash and reuse probe (segment, chunk)
 ``ds.jit.compile``          jit-cache miss: trace, lower, compile
 ``ds.dispatch``             calling a non-streamed executable or host op
+``ds.upload``               copying a registry leaf to the device once,
+                            for the reuse cache, until the copy is ready
 ``ds.stream.fingerprint``   bucket and replicated-operand fingerprints
 ``ds.stream.dispatch``      calling the bucket executable, with the
                             host-to-device copy of its numpy slice
