@@ -68,6 +68,13 @@ PROBE_MIN_COST_S = MIN_CACHE_COST_S
 # copy it saves.
 H2D_BW = 8.1e9        # bytes/s
 
+# Largest host-to-device copy the mesh lane makes in one call. On a TPU
+# v5e one `device_put` of a 4.8 GB float64 array took 23 s (0.21 GB/s),
+# while 0.8-2.4 GB took 0.10-0.37 s (5-8 GB/s; PERF.md section 5), so a
+# larger device part is copied in row pieces of at most this size and
+# joined on its device (`LineageRuntime._place`).
+PLACE_PIECE_BYTES = 1 << 31
+
 
 def _numel(shape) -> int:
     n = 1

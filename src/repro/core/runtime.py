@@ -55,6 +55,8 @@ class ShardLog:
     reshards: int = 0                 # reshard (all-gather) boundaries run
     collectives: int = 0              # psum-carrying shard_* reduces run
     collective_bytes: int = 0         # total link bytes (cost-model est.)
+    placements: int = 0               # host operands placed on the mesh
+    placed_bytes: int = 0             # their host bytes
 
     @property
     def total(self) -> int:
@@ -66,7 +68,9 @@ class ShardLog:
                     config_sharded_segments=self.config_sharded_segments,
                     reshards=self.reshards,
                     collectives=self.collectives,
-                    collective_bytes=self.collective_bytes)
+                    collective_bytes=self.collective_bytes,
+                    placements=self.placements,
+                    placed_bytes=self.placed_bytes)
 
 
 @dataclass
@@ -252,11 +256,15 @@ class _RunCtx:
     `run_plan` calls on one runtime cannot alias each other's
     ownership. `leaves` maps each dense registry leaf this run bound
     as a host array to the key of its device copy in the reuse cache
-    (`LineageRuntime._upload_keys`); empty without a cache."""
+    (`LineageRuntime._upload_keys`); empty without a cache. `placed`
+    maps each value a sharded segment read from the host to its shard
+    tag and its copy on the mesh (`LineageRuntime._mesh_args`), freed
+    with the value."""
 
     depth: int = 1
     owned: set = field(default_factory=set)
     leaves: dict = field(default_factory=dict)
+    placed: dict = field(default_factory=dict)
 
 
 @dataclass
@@ -541,6 +549,72 @@ class LineageRuntime:
             dev.block_until_ready()
         return dev
 
+    def _mesh_args(self, seg, args: list, tags: tuple, jmesh,
+                   rctx: _RunCtx) -> list:
+        """`args` of a sharded segment with each host array placed on
+        the mesh by its shard tag ('s': rows split on the data axis,
+        'r': replicated), once a run: a later sharded segment that reads
+        the same value gets the placed copy. `values` keeps the host
+        array, since a local segment's executable does not take a
+        mesh-placed one. A device array is not counted as a placement;
+        where its layout differs from the tag's it is moved to the
+        tag's layout on the devices, which the cached executable takes
+        whatever arguments it was compiled from."""
+        import jax
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from repro.distributed.mesh import DATA_AXIS
+        out = list(args)
+        for i, (u, tag) in enumerate(zip(seg.input_uids, tags)):
+            got = rctx.placed.get(u)
+            a = out[i]
+            target = NamedSharding(jmesh, P(DATA_AXIS, None) if tag == "s"
+                                   else P())
+            if got is not None and got[0] == tag:
+                out[i] = got[1]
+            elif isinstance(a, jax.Array):
+                if not a.sharding.is_equivalent_to(target, a.ndim):
+                    out[i] = jax.device_put(a, target)
+            elif isinstance(a, np.ndarray):
+                with self.stats.spans("ds.shard.place"):
+                    dev = self._place(a, target)
+                log = self.stats.shard
+                log.placements += 1
+                log.placed_bytes += a.nbytes
+                rctx.placed[u] = (tag, dev)
+                out[i] = dev
+        return out
+
+    @staticmethod
+    def _place(host: np.ndarray, target):
+        """`host` on the mesh by the sharding `target`, ready. Each
+        device's part larger than `costmodel.PLACE_PIECE_BYTES` crosses
+        in row pieces of at most that size, one at a time, and is joined
+        on its device: the chip copies one very large array far slower
+        than the same bytes in pieces (see the constant)."""
+        import jax
+        import jax.numpy as jnp
+        if host.ndim == 0 or host.nbytes <= costmodel.PLACE_PIECE_BYTES:
+            dev = jax.device_put(host, target)
+            dev.block_until_ready()
+            return dev
+        step = max(1, costmodel.PLACE_PIECE_BYTES // host[:1].nbytes)
+        shards = []
+        for d, idx in target.addressable_devices_indices_map(
+                host.shape).items():
+            part = host[idx]
+            pieces = []
+            for r in range(0, part.shape[0], step):
+                pieces.append(jax.device_put(part[r:r + step], d))
+                pieces[-1].block_until_ready()
+            shards.append(pieces[0] if len(pieces) == 1
+                          else jnp.concatenate(pieces))
+        dev = jax.make_array_from_single_device_arrays(host.shape, target,
+                                                       shards)
+        dev.block_until_ready()
+        return dev
+
     # ------------------------------------------------------------------
     def _run_instructions(self, plan: Plan, values: dict[int, Any],
                           lin: dict[int, str]) -> None:
@@ -615,6 +689,7 @@ class LineageRuntime:
         jmesh = (mesh_spec.jax_mesh() if mesh_spec is not None else None)
         for seg in segments:
             batched = bctx is not None and seg.variant
+            mesh_tags = None    # shard tags of a segment run on the mesh
             self.stats.segments += 1
             if batched:
                 self.stats.batched_segments += 1
@@ -638,6 +713,7 @@ class LineageRuntime:
                                           values, lin, lmemo, jcache,
                                           rctx=rctx)
                 self._free(values, seg.frees)
+                self._free(rctx.placed, seg.frees)
                 continue
             if batched:
                 axes = "".join("0" if u in bctx.bvals else "-"
@@ -656,9 +732,9 @@ class LineageRuntime:
                 if jmesh is not None:
                     from .jit_cache import mesh_key_tag
                     from .segments import shard_specs
-                    in_t, out_t = shard_specs(seg)
+                    mesh_tags, out_t = shard_specs(seg)
                     seg_key += mesh_key_tag(mesh_spec.key_tag(),
-                                            in_t, out_t)
+                                            mesh_tags, out_t)
                     self._meter_shard_segment(seg)
                 else:
                     seg_key += "|unshard"  # local-equivalent fallback
@@ -678,14 +754,18 @@ class LineageRuntime:
                         # executable — the segment minus the probe value
                         # and everything only it needed — mirroring what
                         # the interpreter computes after the same hit
+                        if local:
+                            args = self._device_args(seg, args, rctx)
+                        elif mesh_tags is not None:
+                            args = self._mesh_args(seg, args, mesh_tags,
+                                                   jmesh, rctx)
                         self._run_compensation(
-                            seg, seg_key, fmts,
-                            self._device_args(seg, args, rctx)
-                            if local else args, rest, last.out_id,
+                            seg, seg_key, fmts, args, rest, last.out_id,
                             jcache, values,
                             bctx=bctx if batched else None,
                             jmesh=jmesh, rctx=rctx)
                     self._free(values, seg.frees)
+                    self._free(rctx.placed, seg.frees)
                     continue
             if last.node.op in backend.NON_TRACEABLE_OPS:
                 # host-path segment (always single-instruction): the
@@ -715,6 +795,9 @@ class LineageRuntime:
                         _reuse_nbytes(args[i]) for i in don)
                 if local:
                     args = self._device_args(seg, args, rctx)
+                elif mesh_tags is not None:
+                    args = self._mesh_args(seg, args, mesh_tags, jmesh,
+                                           rctx)
                 try:
                     outs = self._execute_cached(
                         seg_key, self._seg_builder(seg, fmts,
@@ -752,6 +835,7 @@ class LineageRuntime:
                 # never be donated out from under a future hit
                 rctx.owned.discard(last.out_id)
             self._free(values, seg.frees)
+            self._free(rctx.placed, seg.frees)
 
     # ------------------------------------------------------------------
     @staticmethod

@@ -21,6 +21,8 @@ they cover. The names are fixed (no per-segment strings):
 ``ds.dispatch``             calling a non-streamed executable or host op
 ``ds.upload``               copying a registry leaf to the device once,
                             for the reuse cache, until the copy is ready
+``ds.shard.place``          placing a sharded segment's host operand on
+                            the mesh, once a run, until the copy is ready
 ``ds.stream.fingerprint``   bucket and replicated-operand fingerprints
 ``ds.stream.dispatch``      calling the bucket executable, with the
                             host-to-device copy of its numpy slice
