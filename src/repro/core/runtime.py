@@ -78,7 +78,7 @@ class StreamLog:
     """Out-of-core streaming meter (chunked segments, ROADMAP item 4):
     how many row buckets were dispatched vs served from the chunk-level
     lineage cache, the payload bytes moved through device memory, and
-    the high-water mark of resident state (one live chunk's inputs plus
+    the high-water mark of resident state (the live chunks' inputs plus
     the running partial aggregates) — the quantity the chunk-size
     selection bounds by `costmodel.CHUNK_MEM_BUDGET`."""
 
@@ -87,8 +87,10 @@ class StreamLog:
     chunks_reused: int = 0     # buckets served from chunk-level lineage
     combines: int = 0          # partial-aggregate accumulations
     bytes_streamed: int = 0    # input payload bytes moved per dispatch
-    peak_live_bytes: int = 0   # max resident: live chunk + accumulators
+    peak_live_bytes: int = 0   # max resident: live chunks + accumulators
     full_hits: int = 0         # whole-stream reuse short-circuits
+    device_accumulated: int = 0  # buckets whose partials were summed
+                                 # on the device (pipelined dense lane)
 
     @property
     def total(self) -> int:
@@ -102,7 +104,8 @@ class StreamLog:
                     combines=self.combines,
                     bytes_streamed=self.bytes_streamed,
                     peak_live_bytes=self.peak_live_bytes,
-                    full_hits=self.full_hits)
+                    full_hits=self.full_hits,
+                    device_accumulated=self.device_accumulated)
 
 
 @dataclass
@@ -146,16 +149,18 @@ class PipelineLog:
 
     At pipeline depth >= 2 the segment executor stops syncing the
     device at segment boundaries. These are counts; the seconds of
-    issuing dispatches, blocking on results and prepping buckets are
-    the `ds.dispatch`, `ds.wait` and `ds.stream.prep` spans
-    (`RuntimeStats.spans`). All counters stay 0 at depth 1
-    (`REPRO_PIPELINE_DEPTH=1`)."""
+    issuing dispatches, blocking on results, prepping buckets and
+    copying them to the device are the `ds.dispatch`, `ds.wait`,
+    `ds.stream.prep` and `ds.stream.copy` spans (`RuntimeStats.spans`).
+    All counters stay 0 at depth 1 (`REPRO_PIPELINE_DEPTH=1`)."""
 
     async_segments: int = 0   # dispatches returned without a device sync
     prefetch_issued: int = 0  # bucket preps handed to the worker
     prefetch_hits: int = 0    # prepped buckets consumed by a dispatch
     prefetch_cancelled: int = 0  # prepped buckets discarded (cache hit
                                  # raced the prep, or error shutdown)
+    device_copies: int = 0    # prepped buckets the worker copied to the
+                              # device
     donated_buffers: int = 0  # dead intermediate buffers donated to XLA
     donated_bytes: int = 0    # their payload bytes
     rebatches: int = 0        # serving batches coalesced while another
@@ -171,6 +176,7 @@ class PipelineLog:
                     prefetch_issued=self.prefetch_issued,
                     prefetch_hits=self.prefetch_hits,
                     prefetch_cancelled=self.prefetch_cancelled,
+                    device_copies=self.device_copies,
                     donated_buffers=self.donated_buffers,
                     donated_bytes=self.donated_bytes,
                     rebatches=self.rebatches)
@@ -1040,12 +1046,18 @@ class LineageRuntime:
         to hashing the slice, so the chunk cache is shared across
         depths) and a bounded single-worker thread slices/pads the NEXT
         miss bucket's arguments while the device computes the current
-        one. Cache lookups, meter updates and accumulation stay on the
-        main thread; the worker only does pure numpy prep. Worker
+        one. On the dense lane (no BCOO slice, no `concat` output) a
+        worker per bucket in flight also copies the slice to the
+        device, and the partials
+        stay device arrays summed on the device in bucket order: the
+        stream makes no host round trip per bucket, and its consumer
+        reads the accumulators where they are. Cache lookups, meter
+        updates and accumulation stay on the main thread. Worker
         errors propagate to the caller via `Future.result()` and the
         `finally` shutdown cancels queued preps so no thread outlives
         the stream. Depth 1 takes the pre-pipeline loop verbatim.
         """
+        import jax.numpy as jnp
         reuse = self.cache is not None
         log = self.stats.streaming
         spans = self.stats.spans
@@ -1156,7 +1168,10 @@ class LineageRuntime:
                 modes[uid] = "keep"
         accs: dict[int, Any] = {u: None for u in seg.output_uids}
 
-        def _accumulate(parts, live: int) -> None:
+        def _accumulate(parts, live: int, on_device: bool = False) -> None:
+            # on_device: the sum is a device add in the partial's dtype,
+            # bitwise the host add on IEEE backends; a cached host
+            # partial joins the device sum in its place in bucket order
             with spans("ds.stream.combine"):
                 for uid, p in zip(seg.output_uids, parts, strict=True):
                     prev = accs[uid]
@@ -1166,9 +1181,12 @@ class LineageRuntime:
                     elif prev is None:
                         accs[uid] = p
                     elif mode == "sum":
-                        accs[uid] = prev + p
+                        accs[uid] = jnp.add(prev, p) if on_device \
+                            else prev + p
                         log.combines += 1
                     # "keep": chunk-invariant — the first value stands
+                if on_device:
+                    log.device_accumulated += 1
                 acc_bytes = sum(_reuse_nbytes(v) for v in accs.values()
                                 if v is not None)
                 log.peak_live_bytes = max(log.peak_live_bytes,
@@ -1224,10 +1242,15 @@ class LineageRuntime:
                                        gated=False)
                 _accumulate(parts, live)
         else:
+            # the dense lane keeps buckets and partials on the device; a
+            # concat output would pile whole rows up there and a BCOO
+            # slice is built on the host, so both keep the host path
+            on_device = ("concat" not in modes.values() and not any(
+                fmts.get(u) == backend.BCOO for u in sliced))
             self._run_chunked_pipelined(
                 seg, seg_key, fmts, values, jcache, host, sliced,
                 rows, c, reuse, rep_fp, cost_each, builder,
-                _accumulate, row_bytes, rctx)
+                _accumulate, row_bytes, rctx, on_device)
         with spans("ds.stream.combine"):
             for uid, m in modes.items():
                 if m == "concat" and accs[uid] is not None:
@@ -1255,7 +1278,8 @@ class LineageRuntime:
                                reuse: bool, rep_fp: str,
                                cost_each: float, builder,
                                accumulate, row_bytes: float,
-                               rctx: _RunCtx) -> None:
+                               rctx: _RunCtx,
+                               on_device: bool = False) -> None:
         """Double-buffered bucket loop (pipeline depth >= 2).
 
         Division of labour, chosen so every shared structure stays
@@ -1270,10 +1294,20 @@ class LineageRuntime:
         previous bucket. Hit buckets never reach the worker — a warm
         append-retrain stream costs zero wasted copies.
 
+        `on_device` (the dense lane): a worker per bucket in flight
+        also copies the slices to the device and waits for the copy
+        (`ds.stream.copy`), the main thread calls the executable on the
+        device buffers and drops them, and the partials stay device
+        arrays, cached as they are and summed on the device, so the
+        stream holds no sync at all: the copies run back to back, up
+        to `pdepth` at once. Otherwise each bucket's partials come back
+        to the host (`ds.wait`) and add in numpy.
+
         `peak_live_bytes` charges the consuming bucket's actual bytes
-        PLUS the next in-flight miss bucket's estimated payload, so the
-        meter honestly reflects two live buckets under
-        `CHUNK_MEM_BUDGET` (chunk_rows sizes buckets with
+        PLUS every in-flight miss bucket's estimated payload, and on the
+        dense lane also the previous bucket, whose program may still
+        read its device buffer, so the meter honestly reflects the live
+        buckets under `CHUNK_MEM_BUDGET` (chunk_rows sizes buckets with
         `CHUNK_LIVE_FACTOR` headroom for exactly this).
 
         A worker exception surfaces on the main thread at
@@ -1281,8 +1315,9 @@ class LineageRuntime:
         mid-flight to the synchronous chunk loop (it costs the
         pipeline, never the answer); any other error propagates.
         Either way the `finally` cancels queued preps (counted as
-        `prefetch_cancelled`) and joins the worker, so an error never
+        `prefetch_cancelled`) and joins the workers, so an error never
         leaves a hung thread or a silently-dropped bucket."""
+        import jax
         log = self.stats.streaming
         plog = self.stats.pipeline
         spans = self.stats.spans
@@ -1329,14 +1364,24 @@ class LineageRuntime:
 
         def _prep_on_worker(s: int, e: int):
             with spans("ds.stream.prep"):
-                return _prep(s, e)
+                args, live = _prep(s, e)
+            if on_device:
+                with spans("ds.stream.copy"):
+                    args = jax.block_until_ready(
+                        [jax.device_put(a) if u in host else a
+                         for u, a in zip(seg.input_uids, args,
+                                         strict=True)])
+            return args, live
 
         bounds = [(s, min(s + c, rows)) for s in range(0, rows, c)]
         pdepth = costmodel.prefetch_depth(row_bytes, len(bounds))
-        ex = ThreadPoolExecutor(max_workers=1,
+        # the device lane gives each bucket in flight a worker: copies
+        # to the device overlap (on a TPU v5e one 64 MB float64 copy
+        # moves 1.4 GB/s, two at once 2.7 GB/s together)
+        ex = ThreadPoolExecutor(max_workers=pdepth if on_device else 1,
                                 thread_name_prefix="chunk-prefetch")
         inflight: deque = deque()  # (s, e, ckey, parts, fut)
-        nxt = 0
+        nxt = prev_live = 0
         try:
             while inflight or nxt < len(bounds):
                 # keep pdepth buckets resolved/in-flight ahead of the
@@ -1424,21 +1469,31 @@ class LineageRuntime:
                     outs = self._execute_cached(seg_key, builder, args,
                                                 jcache, rctx=rctx,
                                                 span="ds.stream.dispatch")
-                    with spans("ds.wait"):
-                        parts = tuple(np.asarray(backend.densify(o))
-                                      for o in outs)
+                    if on_device:
+                        # the program holds its operands until it ends:
+                        # drop ours (the future keeps them too), so X is
+                        # never resident whole
+                        plog.device_copies += 1
+                        args = fut = None
+                        parts = tuple(backend.densify(o) for o in outs)
+                    else:
+                        with spans("ds.wait"):
+                            parts = tuple(np.asarray(backend.densify(o))
+                                          for o in outs)
                     log.chunks += 1
                     log.bytes_streamed += live
                     if ckey is not None:
                         self.cache.put(ckey, parts, cost_each,
                                        gated=False)
-                # charge the NEXT in-flight miss bucket alongside this
-                # one: its args are (being) materialized concurrently
-                nxt_live = 0
-                if inflight and inflight[0][3] is None:
-                    n_rows = inflight[0][1] - inflight[0][0]
-                    nxt_live = int(n_rows * row_bytes)
-                accumulate(parts, live + nxt_live)
+                # charge the in-flight miss buckets alongside this one
+                # (their args are being materialized concurrently) and,
+                # on the device lane, the last dispatched one, whose
+                # program may still read its buffer
+                ahead = sum(int((e2 - s2) * row_bytes)
+                            for s2, e2, _, p2, _ in inflight if p2 is None)
+                accumulate(parts, live + prev_live + ahead, on_device)
+                if on_device and live:
+                    prev_live = live
         finally:
             while inflight:
                 _, _, _, _, fut = inflight.popleft()

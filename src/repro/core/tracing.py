@@ -25,10 +25,12 @@ they cover. The names are fixed (no per-segment strings):
                             the mesh, once a run, until the copy is ready
 ``ds.stream.fingerprint``   bucket and replicated-operand fingerprints
 ``ds.stream.dispatch``      calling the bucket executable, with the
-                            host-to-device copy of its numpy slice
+                            host-to-device copy of a numpy slice
 ``ds.stream.combine``       accumulating and concatenating partials
 ``ds.stream.prep``          slicing a bucket on the prefetch worker
-                            (overlapped; the only worker-thread span)
+                            (overlapped, like ``ds.stream.copy``)
+``ds.stream.copy``          the prefetch worker copying a dense bucket
+                            to the device, until the copy is ready
 ``ds.wait``                 every host block on device results
 ``ds.serve.batch``          one `ModelServer` batch
 ==========================  ==============================================

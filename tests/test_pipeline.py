@@ -15,6 +15,11 @@ The contract under test:
     bindings, reuse-cache entries and probe-hit values survive any
     number of donating runs, and donated executables live under a
     separate `|don:`-suffixed jit-cache key;
+  * on the dense lane the worker copies each bucket to the device and
+    the partials are summed there: one sync a stream whatever the
+    bucket count, bitwise depth 1's answer with the chunk cache on or
+    off (a depth-1 cache's host partials join the device sum in place);
+    `concat` and BCOO streams keep the host path;
   * an injected prefetch-worker fault is absorbed by the fault policy
     — the worker is joined and the stream finishes on the synchronous
     chunk loop with the exact answer; a genuine worker error (and any
@@ -172,10 +177,129 @@ def test_peak_live_bytes_under_budget_with_prefetch(rng, monkeypatch):
     s = rt.stats.streaming
     assert s.chunks > 1
     assert rt.stats.pipeline.prefetch_issued > 1
-    # the meter charges BOTH in-flight buckets, and still fits: the
-    # bucket sizing keeps CHUNK_LIVE_FACTOR headroom per slice
-    assert 0 < s.peak_live_bytes <= BUDGET
+    assert rt.stats.pipeline.device_copies == s.chunks
+    # the meter charges the consumed device bucket, the one the worker
+    # holds and the last dispatched one (its program may still read it)
+    # plus the accumulators, and still fits: the bucket sizing keeps
+    # CHUNK_LIVE_FACTOR headroom per slice
+    row_bytes = 9 * 8
+    bucket = costmodel.chunk_rows(row_bytes) * row_bytes
+    accs = (8 * 8 + 8) * 8
+    assert 3 * bucket + accs <= s.peak_live_bytes <= BUDGET
     assert _no_prefetch_threads()
+
+
+# ---------------------------------------------------------------------------
+# the dense lane: buckets copied on the worker, partials summed on device
+# ---------------------------------------------------------------------------
+
+def _fixed_buckets(monkeypatch, rows: int = 512):
+    """Buckets of `rows` rows whatever the payload, so a test picks the
+    bucket count by the row count."""
+    monkeypatch.setattr(costmodel, "CHUNK_MEM_BUDGET", BUDGET)
+    monkeypatch.setattr(costmodel, "chunk_rows", lambda row_bytes: rows)
+
+
+def test_device_lane_waits_once_a_stream(rng, monkeypatch):
+    _fixed_buckets(monkeypatch)
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "2")
+    waits = {}
+    for buckets in (4, 16):
+        Xh = rng.normal(size=(512 * buckets, 8))
+        yh = rng.normal(size=(512 * buckets,))
+        rt = LineageRuntime(cache=None, fuse=True)
+        got = _lm_run(rt, Xh, yh)
+        assert np.abs(got - _lm_ref(Xh, yh).ravel()).max() < 1e-10
+        s, p, spans = rt.stats.streaming, rt.stats.pipeline, rt.stats.spans
+        assert s.chunks == buckets
+        assert p.device_copies == s.device_accumulated == s.chunks
+        assert spans.n("ds.stream.copy") == buckets
+        waits[buckets] = spans.n("ds.wait")
+        assert rt.stats.as_dict()["pipeline"]["device_copies"] == buckets
+        assert rt.stats.as_dict()["streaming"]["device_accumulated"] \
+            == buckets
+    # no wait per bucket: the stream's partials never leave the device
+    assert waits[4] == waits[16] <= 3
+    assert _no_prefetch_threads()
+
+
+@pytest.mark.parametrize("reuse", [False, True])
+def test_device_lane_bitwise_depth1(rng, monkeypatch, reuse):
+    _fixed_buckets(monkeypatch)
+    Xh, yh = rng.normal(size=(512 * 6 + 77, 8)), rng.normal(size=(3149,))
+    got = {}
+    for depth in ("1", "2"):
+        monkeypatch.setenv("REPRO_PIPELINE_DEPTH", depth)
+        rt = LineageRuntime(cache=ReuseCache() if reuse else None,
+                            fuse=True)
+        got[depth] = _lm_run(rt, Xh, yh)
+        s = rt.stats.streaming
+        assert s.chunks == 7
+        assert s.device_accumulated == (7 if depth == "2" else 0)
+    assert np.array_equal(got["1"], got["2"])
+
+
+def test_depth1_chunk_cache_joins_the_device_sum(rng, monkeypatch):
+    # a depth-1 run caches host partials; at depth 2 a corrected X hits
+    # them for every bucket but one, which is copied and computed on the
+    # device: the mixed sum is bitwise a fresh depth-1 run's
+    _fixed_buckets(monkeypatch)
+    Xh, yh = rng.normal(size=(512 * 5, 8)), rng.normal(size=(512 * 5,))
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "1")
+    rt = LineageRuntime(cache=ReuseCache(), fuse=True)
+    _lm_run(rt, Xh, yh)
+    Xc = Xh.copy()
+    Xc[512 * 2 + 7, 3] = 42.0
+    want = _lm_run(LineageRuntime(cache=None, fuse=True), Xc, yh)
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "2")
+    s, p = rt.stats.streaming, rt.stats.pipeline
+    chunks0, reused0 = s.chunks, s.chunks_reused
+    got = _lm_run(rt, Xc, yh)
+    assert s.chunks - chunks0 == 1
+    assert s.chunks_reused - reused0 == 4
+    assert p.device_copies == 1
+    assert s.device_accumulated == 5
+    assert np.array_equal(got, want)
+
+
+def test_concat_stream_keeps_the_host_path(rng, monkeypatch):
+    # Z = exp(X) escapes the colSums stream into the gram stream, so the
+    # first stream concatenates Z's buckets on the host; only the second
+    # runs on the device lane
+    monkeypatch.setattr(costmodel, "CHUNK_MEM_BUDGET", BUDGET)
+    Xh = rng.normal(size=(4096, 8))
+    got = {}
+    for depth in ("1", "2"):
+        monkeypatch.setenv("REPRO_PIPELINE_DEPTH", depth)
+        Z = ops.exp(input_tensor("X", Xh))
+        W = Z * ops.colSums(Z)
+        rt = LineageRuntime(cache=None, fuse=True)
+        got[depth] = np.asarray(rt.evaluate([W.T @ W])[0])
+        s = rt.stats.streaming
+        assert s.chunked_segments == 2
+    per_stream = s.chunks // 2
+    assert rt.stats.pipeline.device_copies == per_stream
+    assert s.device_accumulated == per_stream
+    assert np.array_equal(got["1"], got["2"])
+    Wh = np.exp(Xh) * np.exp(Xh).sum(axis=0, keepdims=True)
+    assert np.abs(got["2"] - Wh.T @ Wh).max() < 1e-9 * np.abs(Wh).max() ** 2
+
+
+def test_bcoo_stream_keeps_the_host_path(rng, monkeypatch):
+    monkeypatch.setattr(costmodel, "CHUNK_MEM_BUDGET", BUDGET)
+    Xh = rng.normal(size=(8192, 32)) * (rng.random((8192, 32)) < 0.1)
+    yh = rng.normal(size=(8192,))
+    got = {}
+    for depth in ("1", "2"):
+        monkeypatch.setenv("REPRO_PIPELINE_DEPTH", depth)
+        rt = LineageRuntime(cache=None, fuse=True, sparse_inputs=True)
+        got[depth] = _lm_run(rt, Xh, yh)
+    s, p = rt.stats.streaming, rt.stats.pipeline
+    assert s.chunks > 1 and p.prefetch_issued == s.chunks
+    assert p.device_copies == s.device_accumulated == 0
+    assert rt.stats.spans.n("ds.stream.copy") == 0
+    assert np.array_equal(got["1"], got["2"])
+    assert np.abs(got["2"] - _lm_ref(Xh, yh).ravel()).max() < 1e-10
 
 
 # ---------------------------------------------------------------------------
@@ -293,6 +417,13 @@ def test_prefetch_error_degrades_to_sync_tail(rng, monkeypatch):
     assert np.abs(got - _lm_ref(Xh, yh).ravel()).max() < 1e-10
     assert rt.stats.faults.degradations == 1
     assert rt.stats.faults.injected == 1
+    # the first prep died: every bucket took the host tail, and the
+    # degraded answer is depth 1's exactly
+    assert rt.stats.pipeline.device_copies == 0
+    assert rt.stats.streaming.device_accumulated == 0
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "1")
+    assert np.array_equal(got, _lm_run(LineageRuntime(cache=None), Xh, yh))
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "2")
     # clean shutdown: queued preps cancelled, worker joined
     assert _no_prefetch_threads()
     # and the runtime is not poisoned: the next run (healthy worker)
@@ -301,6 +432,36 @@ def test_prefetch_error_degrades_to_sync_tail(rng, monkeypatch):
     again = _lm_run(rt, Xh, yh)
     assert np.abs(again - _lm_ref(Xh, yh).ravel()).max() < 1e-10
     assert rt.stats.faults.degradations == 1  # healthy run added none
+    assert _no_prefetch_threads()
+
+
+def test_prefetch_error_mid_stream_joins_host_tail_to_device_sum(
+        rng, monkeypatch):
+    # a fault at the third prep: two buckets were summed on the device,
+    # the tail's host partials join those accumulators, and the answer
+    # is still depth 1's exactly
+    monkeypatch.setattr(costmodel, "CHUNK_MEM_BUDGET", BUDGET)
+    Xh, yh = rng.normal(size=(4096, 8)), rng.normal(size=(4096,))
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "1")
+    want = _lm_run(LineageRuntime(cache=None), Xh, yh)
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", "2")
+    real = rt_mod._reuse_nbytes
+    third = Xh[2 * 128:3 * 128]         # the third 128-row bucket of X
+
+    def boom(a):
+        if threading.current_thread().name.startswith("chunk-prefetch") \
+                and np.array_equal(a, third):
+            raise faults.InjectedFault("prefetch boom")
+        return real(a)
+
+    monkeypatch.setattr(rt_mod, "_reuse_nbytes", boom)
+    rt = LineageRuntime(cache=None, fuse=True)
+    got = _lm_run(rt, Xh, yh)
+    assert rt.stats.faults.degradations == 1
+    assert rt.stats.pipeline.device_copies == 2
+    assert rt.stats.streaming.device_accumulated == 2
+    assert rt.stats.streaming.chunks == 32
+    assert np.array_equal(got, want)
     assert _no_prefetch_threads()
 
 
