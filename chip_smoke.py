@@ -31,7 +31,7 @@ elsewhere; `--rehearse` shrinks the sizes and accepts the CPU:
     XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \\
         python chip_smoke.py --rehearse --chips 4
 
-Times printed are smoke timings of one cold run, not benchmarks. The
+Times printed are smoke timings of one cold run, not the benchmark's. The
 last line of standard output is one JSON object,
 ``{"ok": true, "device": {"platform", "kind", "count"}}``.
 """

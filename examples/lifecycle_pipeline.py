@@ -85,9 +85,9 @@ def main():
     # -- HPO sweep with lineage reuse (Fig. 5 workload).
     # mode='sequential' pins the per-λ-plan + reuse-cache execution this
     # section narrates; the default auto mode would compile the whole
-    # grid into one batched vmapped plan (see examples/parfor usage in
-    # README / benchmarks/parfor_bench.py) where gram/xtv never need
-    # the cache — computed once in the config-invariant prefix.
+    # grid into one batched vmapped plan (see `parfor` in the README)
+    # where gram/xtv never need the cache — computed once in the
+    # config-invariant prefix.
     lambdas = np.logspace(-3, 2, 12).tolist()
     betas, losses = grid_search_lm(X, Y, lambdas, runtime=rt,
                                    mode="sequential")

@@ -571,12 +571,11 @@ def prefetch_depth(row_bytes: float, n_chunks: int) -> int:
 # backoff, consumed by the runtime's federated/IO recovery ladders
 # ---------------------------------------------------------------------------
 
-# Per-site RPC timeout. Generous by default — a first call includes the
-# per-site jit compile, and a clean run must never trip the ladder; the
-# chaos tests force it down via env to exercise the timeout path
-# against injected stragglers. In-process sites cannot be preempted, so
-# the timeout binds at the attempt boundary: a late result is
-# discarded, counted, and the call retried.
+# Per-site RPC timeout. Generous — a first call includes the per-site
+# jit compile, and a clean run must never trip the ladder. In-process
+# sites cannot be preempted, so the timeout binds at the attempt
+# boundary: a late result is discarded, counted, and the call retried.
+# Read at call time, so a test can patch it.
 FED_TIMEOUT_S = 30.0
 
 # Exponential-backoff base: sleep RETRY_BASE_S * 2^(attempt-1) before
@@ -589,23 +588,13 @@ RETRY_BASE_S = 0.01
 MAX_RETRIES = 2
 
 
-def fed_timeout_s() -> float:
-    """Per-site RPC timeout (env ``REPRO_FED_TIMEOUT_S``), read per
-    call like the pipeline knobs so one process can compare policies."""
-    return float(os.environ.get("REPRO_FED_TIMEOUT_S", FED_TIMEOUT_S))
-
-
 def retry_base_s() -> float:
     return float(os.environ.get("REPRO_RETRY_BASE_S", RETRY_BASE_S))
 
 
-def max_retries() -> int:
-    return int(os.environ.get("REPRO_MAX_RETRIES", MAX_RETRIES))
-
-
 def retry_backoff_s(attempt: int) -> float:
     """Backoff before re-attempt `attempt` (1-based): exponential in
-    the attempt number, bounded by the caller's `max_retries` loop."""
+    the attempt number, bounded by the caller's `MAX_RETRIES` loop."""
     return retry_base_s() * (2.0 ** max(attempt - 1, 0))
 
 

@@ -387,7 +387,7 @@ class FaultLog:
                     `fired` dict carries the injection-side count)
       retries       recovery re-attempts taken (site RPC + chunk IO)
       timeouts      site calls whose wall time exceeded
-                    `costmodel.fed_timeout_s()` (result discarded,
+                    `costmodel.FED_TIMEOUT_S` (result discarded,
                     call retried — in-process sites cannot be
                     preempted, so the timeout binds at the attempt
                     boundary)

@@ -25,8 +25,8 @@ Two execution paths share these instruction semantics:
     jit cache, so per-site gram runs the Pallas/BCOO kernels and
     repeated runs replay warm executables);
   * the **eager numpy methods** on `FederatedTensor` (`fed_mv`,
-    `fed_gram`, ...) — the in-process oracle used by tests and the
-    eager-numpy baseline in `benchmarks/federated_bench.py`.
+    `fed_gram`, ...) — the in-process oracle the tests compare the
+    compiled plans against.
 
 The multi-pod mesh backend lives in `repro.distributed.fedavg`: sites =
 slices along the `pod` mesh axis, instructions lower to shard_map

@@ -12,15 +12,13 @@ dispatch-time signature checks; the runtime books that compile as its
 
 The cache is bounded: LRU eviction on BOTH an entry cap and a resident
 code-byte cap (executable size from XLA's `memory_analysis` when
-available, a flat estimate otherwise), configurable via
-``REPRO_JIT_CACHE_ENTRIES`` / ``REPRO_JIT_CACHE_BYTES`` — long sessions
-sweeping many plan shapes (benchmark suites, growing `parfor` grids)
-stay at a bounded footprint, and eviction/hit/miss counters surface in
+available, a flat estimate otherwise) — long sessions sweeping many
+plan shapes (growing `parfor` grids) stay at a bounded footprint, and
+eviction/hit/miss counters surface in
 `RuntimeStats.as_dict()['jit_cache']`.
 """
 from __future__ import annotations
 
-import os
 import warnings
 from collections import OrderedDict
 from contextlib import contextmanager
@@ -32,11 +30,9 @@ from jax.experimental.sparse import BCOO as _BCOO
 
 from . import faults
 
-# Defaults for the process-wide cache; overridable per-process via env
-# so benchmark drivers / services can pin their own budget.
-DEFAULT_CAPACITY = int(os.environ.get("REPRO_JIT_CACHE_ENTRIES", 512))
-DEFAULT_BYTE_CAPACITY = int(
-    os.environ.get("REPRO_JIT_CACHE_BYTES", 256 << 20))
+# Caps of the process-wide cache.
+DEFAULT_CAPACITY = 512
+DEFAULT_BYTE_CAPACITY = 256 << 20
 # Executables that expose no memory analysis are charged a flat size so
 # the byte cap still exerts pressure instead of silently unbounding.
 FALLBACK_EXE_BYTES = 64 << 10

@@ -1561,7 +1561,7 @@ class LineageRuntime:
         collect-and-recompute fallback run when every attempt failed
         but the site's DATA survives. In-process sites cannot be
         preempted, so the timeout binds at the attempt boundary: a
-        call slower than `costmodel.fed_timeout_s()` has its (late)
+        call slower than `costmodel.FED_TIMEOUT_S` has its (late)
         result discarded and is retried — sound because site kernels
         are pure, a recompute yields the same value. Latencies route
         through the `StepMonitor` straggler flagging and successful
@@ -1570,9 +1570,9 @@ class LineageRuntime:
         if not faults.policy_enabled():
             return rpc(self.stats), False
         flog = self.stats.faults
-        timeout = costmodel.fed_timeout_s()
+        timeout = costmodel.FED_TIMEOUT_S
         last_err: Optional[BaseException] = None
-        for attempt in range(costmodel.max_retries() + 1):
+        for attempt in range(costmodel.MAX_RETRIES + 1):
             if attempt:
                 pause = costmodel.retry_backoff_s(attempt)
                 flog.retries += 1

@@ -22,7 +22,7 @@ def _fault_read(f, chunk_bytes: int, fault_log=None) -> bytes:
     (`repro.core.faults`), with bounded exponential-backoff retry.
 
     Transient IO errors — injected or real `OSError`s — are retried up
-    to `costmodel.max_retries()` times; the file position is untouched
+    to `costmodel.MAX_RETRIES` times; the file position is untouched
     by a failed attempt (injection fires *before* the read), so a
     retry resumes the stream exactly where it left off. `fault_log`
     (a `FaultLog`) meters injected/retries/backoff_s when given.
@@ -30,7 +30,7 @@ def _fault_read(f, chunk_bytes: int, fault_log=None) -> bytes:
     from repro.core import costmodel, faults
     if not faults.policy_enabled():
         return f.read(chunk_bytes)
-    tries = costmodel.max_retries() + 1
+    tries = costmodel.MAX_RETRIES + 1
     for attempt in range(tries):
         try:
             faults.io_entry("read_csv_chunks")

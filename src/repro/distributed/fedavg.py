@@ -8,7 +8,7 @@ apply the average. Cross-site traffic per sync = one (compressed) param
 delta instead of H gradient all-reduces — the knob that makes the pod
 axis tolerant of slow inter-pod links (DCN vs ICI).
 
-This is the host-level simulation used by tests/benchmarks; on a real
+This is the host-level simulation used by the tests; on a real
 multi-pod mesh the same schedule maps to a shard_map over the `pod`
 axis (params carry a leading pod dim between syncs).
 """

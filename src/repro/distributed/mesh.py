@@ -22,8 +22,7 @@ sharded segments through the local-equivalent kernels, bit-compatible
 with the sharded path.
 
 CPU repro: run under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-to get an 8-device host mesh (see tests/test_sharded.py and
-benchmarks/distributed_bench.py).
+to get an 8-device host mesh (see tests/test_sharded.py).
 """
 from __future__ import annotations
 

@@ -164,7 +164,7 @@ class TestFederatedRecovery:
         # every call to site 1 sleeps past the timeout; the attempt-
         # boundary timeout discards the (late) result, retries, then
         # degrades. site_slow never raises -> injected stays 0.
-        monkeypatch.setenv("REPRO_FED_TIMEOUT_S", "0.01")
+        monkeypatch.setattr(costmodel, "FED_TIMEOUT_S", 0.01)
         x, y = fed_data
         w0, _, _ = _fed_run(x, y, intercept=False)
         w1, rt, plan = _fed_run(
@@ -293,7 +293,7 @@ class TestStreamingRecovery:
         write_csv(path, xh)
         flog = faults.FaultLog()
         # two injected IO errors on the first byte-window read, healed
-        # by backoff retry (max_retries=2 -> third attempt lands)
+        # by backoff retry (MAX_RETRIES=2 -> third attempt lands)
         with faults.inject("seed=5;chunk_io@0,1"):
             chunks = list(read_csv_chunks(path, 64, chunk_bytes=1 << 12,
                                           fault_log=flog))

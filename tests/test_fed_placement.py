@@ -234,6 +234,24 @@ class TestFederatedReuse:
             assert rt.stats.exchange.as_dict() == \
                 one.stats.exchange.as_dict()
 
+    def test_reused_grid_matches_eager_oracle(self, data):
+        """A compiled λ grid with a reuse cache returns the eager
+        oracle's betas for every λ, and moves the bytes of ONE eager
+        solve: fed_gram/fed_xtv cross the sites once for the grid."""
+        x, y = data
+        lams = (0.01, 0.1, 1.0, 10.0, 100.0)
+        X = federated_input("ogX", FederatedTensor.partition_rows(x, 3))
+        Y = input_tensor("ogy", y)
+        rt = LineageRuntime(cache=ReuseCache())
+        got = [rt.run_plan(compile_plan([_lmds_graph(X, Y, reg=lam)],
+                                        reuse_enabled=True))[0]
+               for lam in lams]
+        fed = FederatedTensor.partition_rows(x, 3)
+        want = [federated_lmds(fed, y, reg=lam) for lam in lams]
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-8, atol=1e-10)
+        assert rt.stats.exchange.total == fed.log.total // len(lams)
+
     def test_per_site_work_hits_jit_cache_on_repeat(self, data):
         x, y = data
         X = federated_input("wX", FederatedTensor.partition_rows(x, 3))

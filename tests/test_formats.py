@@ -132,6 +132,49 @@ class TestDenseSparseParity:
         # not one dispatch per instruction
         assert rt.stats.segments < rt.stats.instructions / 2
 
+    @pytest.mark.parametrize("density", [0.05, 1.0])
+    def test_reuse_active_ridge_plan_fuses(self, rng, density):
+        # cost-gated probe points keep the ridge plan at a few fused
+        # segments with a cache on, on the BCOO path as on the dense one
+        xn = _sparse_mat(rng, 256, 64, density)
+        x = input_tensor("X", xn)
+        y = input_tensor("y", rng.normal(size=(256, 1)))
+        beta = ops.solve(ops.gram(x) + 0.1 * ops.eye(64), ops.xtv(x, y))
+        plan = compile_plan([beta], reuse_enabled=True)
+        fmts = plan.formats_for(True)
+        assert (fmts.get(x.node.uid) == backend.BCOO) == (density < 1.0)
+        segs = plan.segments_for(True)
+        assert len(plan.instructions) > 2 * len(segs)
+        assert any(s.fused for s in segs)
+
+    @pytest.mark.parametrize("fuse", [True, False])
+    def test_prepared_sparse_lmds_pipeline_parity(self, rng, fuse):
+        # a BCOO-declared PreparedScript: sparsity-preserving transform,
+        # sparse gram/xtv, dense solve and residual diagnostics, against
+        # the same script on dense inputs
+        from repro.core import PreparedScript
+
+        def pipeline(a, b):
+            xt = ops.sqrt(ops.abs_(a)) * 0.5
+            beta = ops.solve(ops.gram(xt) + 0.1 * ops.eye(64),
+                             ops.xtv(xt, b))
+            err = xt @ beta - b
+            return beta, ops.sum_(err * err), \
+                ops.cbind(ops.colSums(err), ops.colMaxs(err))
+
+        xn = _sparse_mat(rng, 256, 64, 0.05)
+        yn = rng.normal(size=(256, 1))
+        shapes = [xn.shape, yn.shape]
+        sparse = PreparedScript(
+            pipeline, shapes, arg_sparsities=[0.05, 1.0],
+            runtime=LineageRuntime(fuse=fuse, sparse_inputs=True))
+        assert backend.BCOO in sparse.plan.formats_for(True).values()
+        dense = PreparedScript(
+            pipeline, shapes,
+            runtime=LineageRuntime(fuse=True, sparse_inputs=False))
+        for a, b in zip(sparse(xn, yn), dense(xn, yn)):
+            np.testing.assert_allclose(a, b, rtol=1e-8, atol=1e-8)
+
     def test_sparse_reuse_hits_match_interpreter(self, rng):
         xn = _sparse_mat(rng, 256, 64, 0.05)
         yn = rng.normal(size=(256, 1))

@@ -3,9 +3,6 @@ merging, invariant/variant segmentation, vmapped execution parity
 against the sequential-reuse and interpreter paths, bucketed warm
 executables, cost-model arbitration, federated exchange invariants, and
 the bounded jit cache."""
-import os
-import sys
-
 import numpy as np
 import pytest
 
@@ -484,22 +481,3 @@ class TestBoundedJitCache:
         d = rt.stats.as_dict()["jit_cache"]
         assert {"hits", "misses", "evictions", "bytes_cached"} <= set(d)
 
-
-class TestRunAggregation:
-    def test_schema_drift_warns_and_skips(self, tmp_path, capsys,
-                                          monkeypatch):
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-        from benchmarks import run as bench_run
-        good = [dict(benchmark="ok", workload="w", speedup=2.0)]
-        (tmp_path / "BENCH_good.json").write_text(
-            __import__("json").dumps(good))
-        (tmp_path / "BENCH_notalist.json").write_text('{"a": 1}')
-        (tmp_path / "BENCH_empty.json").write_text("[]")
-        (tmp_path / "BENCH_badentry.json").write_text("[1, 2]")
-        (tmp_path / "BENCH_garbage.json").write_text("{unparseable")
-        monkeypatch.setattr(bench_run, "BENCH_DIR", str(tmp_path))
-        bench_run.aggregate()   # must not raise
-        out = capsys.readouterr().out
-        assert "BENCH_good.json" in out and "speedup=2.0" in out
-        for bad in ("notalist", "empty", "badentry", "garbage"):
-            assert f"BENCH_{bad}.json" in out and "skipped" in out

@@ -164,6 +164,33 @@ def test_depth2_append_retrain_reuses_all_old_buckets(rng, monkeypatch):
     assert s.chunks - base_chunks <= extra // 16 + 1
 
 
+@pytest.mark.parametrize("depth", ["1", "2"])
+def test_warm_append_retrain_compiles_nothing(rng, monkeypatch, depth):
+    # once a base fit and an appended fit of these shapes have run, a
+    # fresh job's append-retrain replays warm executables only, reuses
+    # every old bucket and stays inside the budget
+    monkeypatch.setattr(costmodel, "CHUNK_MEM_BUDGET", BUDGET)
+    monkeypatch.setenv("REPRO_PIPELINE_DEPTH", depth)
+    Xh, yh = rng.normal(size=(4096, 8)), rng.normal(size=(4096,))
+    Xa = np.vstack([Xh, rng.normal(size=(409, 8))])
+    ya = np.concatenate([yh, rng.normal(size=(409,))])
+    warm = LineageRuntime(cache=ReuseCache(), fuse=True)
+    _lm_run(warm, Xh, yh)
+    _lm_run(warm, Xa, ya)
+    rt = LineageRuntime(cache=ReuseCache(), fuse=True)
+    _lm_run(rt, Xh, yh)
+    s = rt.stats.streaming
+    base_chunks, base_reused = s.chunks, s.chunks_reused
+    jstats = get_jit_cache().stats
+    miss0 = jstats.misses
+    got = _lm_run(rt, Xa, ya)
+    assert jstats.misses == miss0
+    assert np.abs(got - _lm_ref(Xa, ya).ravel()).max() < 1e-10
+    assert s.chunks_reused - base_reused == base_chunks
+    assert 0 < s.peak_live_bytes <= BUDGET
+    assert (rt.stats.pipeline.prefetch_issued > 0) == (depth == "2")
+
+
 # ---------------------------------------------------------------------------
 # memory bound with prefetch live
 # ---------------------------------------------------------------------------
